@@ -36,6 +36,8 @@ _ROOT = str(Path(__file__).resolve().parent.parent)
 if _ROOT not in sys.path:
     sys.path.insert(0, _ROOT)
 
+from repro.kernels.runtime import enable_compile_cache  # noqa: E402
+
 
 def _run(mod, quick: bool):
     """Call ``mod.run()``, forwarding ``quick`` when the bench supports it."""
@@ -95,6 +97,7 @@ def main(argv=None) -> None:
     ap.add_argument("--quick", action="store_true",
                     help="small shapes / short streams for benches that support it")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from benchmarks import (
         activation_variants,

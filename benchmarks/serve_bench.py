@@ -113,6 +113,7 @@ from pathlib import Path
 import numpy as np
 
 from repro.configs import get_reduced_config
+from repro.kernels.runtime import enable_compile_cache
 from repro.serving.engine import InferenceEngine, ServeConfig
 from repro.serving.faults import make_profile
 from repro.serving.kv_cache import cache_bytes, paged_cache_bytes
@@ -812,6 +813,7 @@ def main(argv=None) -> int:
                     help="virtual pools only (ledger unchanged, no real tokens)")
     ap.add_argument("--out", default=".", help="directory for the BENCH_*.json artifact")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     n = args.n or (56 if args.quick else 96)
     batch = args.batch or 8

@@ -5,7 +5,8 @@ reproduce from the analytical models — every calibrated value is marked
 ``# CAL`` with its derivation (DESIGN.md §2 "Calibration note").
 
 TPU constants are the documented v5e-class estimates used by the roofline
-energy model (DESIGN.md §6).
+energy model (DESIGN.md §6). ``chip_for_device`` maps an attached device's
+``device_kind`` to its constants; a kind without an entry is an error.
 """
 from __future__ import annotations
 
@@ -77,3 +78,16 @@ class TPUChip:
 
 DEFAULT_BOARD = FPGABoard()
 DEFAULT_CHIP = TPUChip()
+
+# Chip constants by ``jax.Device.device_kind``. TPU v5e peaks: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 394 TOP/s int8, 16 GB HBM at
+# 819 GB/s); power and reload terms are the model's estimates above.
+CHIPS: dict[str, TPUChip] = {"TPU v5 lite": DEFAULT_CHIP}
+
+
+def chip_for_device(device_kind: str) -> TPUChip:
+    """Constants of the attached chip; unknown kinds raise (no default)."""
+    if device_kind not in CHIPS:
+        raise KeyError(f"no chip constants for device kind {device_kind!r} "
+                       f"(known: {sorted(CHIPS)})")
+    return CHIPS[device_kind]

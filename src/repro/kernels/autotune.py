@@ -46,7 +46,7 @@ from typing import Callable, Mapping
 
 from repro.core.cost_model import Roofline, chip_for_dtype, dtype_bytes
 from repro.core.energy import DEFAULT_CHIP, TPUChip
-from repro.kernels.runtime import backend_key
+from repro.kernels.runtime import CACHE_DIR, backend_key
 
 F32 = 4
 INT8 = 1
@@ -333,10 +333,8 @@ _LOCK = threading.Lock()
 
 
 def _cache_path() -> str:
-    return os.environ.get(
-        "REPRO_AUTOTUNE_CACHE",
-        os.path.join(tempfile.gettempdir(), "repro_autotune_cache.json"),
-    )
+    return os.environ.get("REPRO_AUTOTUNE_CACHE",
+                          str(CACHE_DIR / "autotune.json"))
 
 
 def cache_key(kernel: str, problem: Mapping[str, int], dtype: str,
@@ -349,8 +347,8 @@ def cache_key(kernel: str, problem: Mapping[str, int], dtype: str,
 
 
 def _valid_entry(value) -> bool:
-    """Disk entries are untrusted (world-shared /tmp default): accept only a
-    flat {block_*: positive int} mapping."""
+    """Disk entries are untrusted (any file at ``REPRO_AUTOTUNE_CACHE``):
+    accept only a flat {block_*: positive int} mapping."""
     return (
         isinstance(value, dict)
         and bool(value)
@@ -376,6 +374,7 @@ def _store_disk(key: str, value: dict) -> None:
     data = _load_disk()
     data[key] = value
     try:
+        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
         fd, tmp = tempfile.mkstemp(dir=os.path.dirname(path) or ".")
         with os.fdopen(fd, "w") as f:
             json.dump(data, f, indent=1, sort_keys=True)

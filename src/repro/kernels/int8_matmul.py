@@ -43,8 +43,8 @@ def _kernel(x_ref, w_ref, sx_ref, sw_ref, o_ref, acc_ref, *, num_k: int):
     @pl.when(ki == num_k - 1)
     def _finalize():
         sx = sx_ref[...]  # (bm, 1) f32
-        sw = sw_ref[...]  # (bn,) f32
-        o_ref[...] = (acc_ref[...].astype(jnp.float32) * sx * sw[None, :]).astype(o_ref.dtype)
+        sw = sw_ref[...]  # (1, bn) f32
+        o_ref[...] = (acc_ref[...].astype(jnp.float32) * sx * sw).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -66,13 +66,15 @@ def _int8_matmul_call(x_q, w_q, x_scale, w_scale, *, block_m: int,
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((bm, 1), lambda i, j, kk: (i, 0)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            # 2-D (1, bn) so Mosaic's tiling of the scale row matches XLA's
+            # for any bn (a 1-D block must be the whole row or 1024-aligned)
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-    )(x_q, w_q, x_scale, w_scale)
+    )(x_q, w_q, x_scale, w_scale.reshape(1, n))
 
 
 def int8_matmul(x_q, w_q, x_scale, w_scale, *, block_m: int | str = 256,

@@ -88,9 +88,11 @@ def _input_projection(x_all, w, sw, b, *, seq: int, bb: int, hidden: int):
     return (zx + b[None, :]).reshape(seq, bb, 4 * hidden)
 
 
-def _layer_recurrence(zx, u, su, table, write, *, impl: str, hidden: int,
+def _layer_recurrence(zx_ref, u, su, table, write, *, impl: str, hidden: int,
                       seq: int, bb: int):
-    """Run one layer's time loop over precomputed input projections ``zx``.
+    """Run one layer's time loop over precomputed input projections, staged
+    in the (S, bb, 4H) VMEM scratch ``zx_ref`` (Mosaic indexes a ref by the
+    loop counter; it cannot dynamically slice a value).
 
     Gate columns arrive PACKED as [i, f, o, g] (the wrappers permute the
     weights): the three sigmoid gates are one contiguous (bb, 3H) VPU pass
@@ -112,7 +114,7 @@ def _layer_recurrence(zx, u, su, table, write, *, impl: str, hidden: int,
         )
         if su is not None:
             zu = zu * su[None, :]
-        z = zx[t] + zu
+        z = zx_ref[t] + zu
         gates = _apply_variant(z[:, : 3 * hidden], impl, "sigmoid", table)
         i = gates[:, :hidden]
         f = gates[:, hidden : 2 * hidden]
@@ -132,22 +134,23 @@ def _kernel(x_ref, w_ref, u_ref, b_ref, *rest, impl: str, hidden: int,
             seq: int, quantized: bool):
     """Single-layer sequence-resident kernel (f32 or int8 weights)."""
     if quantized:
-        sw_ref, su_ref, table_ref, hs_ref, hn_ref, cn_ref = rest
+        sw_ref, su_ref, table_ref, hs_ref, hn_ref, cn_ref, zx_ref = rest
         sw, su = sw_ref[...], su_ref[...]
     else:
-        table_ref, hs_ref, hn_ref, cn_ref = rest
+        table_ref, hs_ref, hn_ref, cn_ref, zx_ref = rest
         sw = su = None
     bb = x_ref.shape[1]
     table = table_ref[...]
     b = b_ref[...].astype(jnp.float32)
 
     x_all = x_ref[...].astype(jnp.float32).reshape(seq * bb, -1)
-    zx = _input_projection(x_all, w_ref[...], sw, b, seq=seq, bb=bb, hidden=hidden)
+    zx_ref[...] = _input_projection(x_all, w_ref[...], sw, b, seq=seq, bb=bb,
+                                    hidden=hidden)
 
     def write(t, h_new):
         hs_ref[t] = h_new.astype(hs_ref.dtype)
 
-    h, c = _layer_recurrence(zx, u_ref[...], su, table, write,
+    h, c = _layer_recurrence(zx_ref, u_ref[...], su, table, write,
                              impl=impl, hidden=hidden, seq=seq, bb=bb)
     hn_ref[...] = h.astype(hn_ref.dtype)
     cn_ref[...] = c.astype(cn_ref.dtype)
@@ -164,9 +167,9 @@ def _stack_kernel(x_ref, w0_ref, wr_ref, u_ref, b_ref, *rest, impl: str,
     the output ref instead.  Per-layer weights keep the packed-gate layout
     and share one activation LUT."""
     if quantized:
-        sw_ref, su_ref, table_ref, hs_ref, hn_ref, cn_ref, seq_scr = rest
+        sw_ref, su_ref, table_ref, hs_ref, hn_ref, cn_ref, seq_scr, zx_ref = rest
     else:
-        table_ref, hs_ref, hn_ref, cn_ref, seq_scr = rest
+        table_ref, hs_ref, hn_ref, cn_ref, seq_scr, zx_ref = rest
     bb = x_ref.shape[1]
     table = table_ref[...]
 
@@ -177,7 +180,8 @@ def _stack_kernel(x_ref, w0_ref, wr_ref, u_ref, b_ref, *rest, impl: str,
         sw = sw_ref[l] if quantized else None
         su = su_ref[l] if quantized else None
         b = b_ref[l].astype(jnp.float32)
-        zx = _input_projection(x_all, w, sw, b, seq=seq, bb=bb, hidden=hidden)
+        zx_ref[...] = _input_projection(x_all, w, sw, b, seq=seq, bb=bb,
+                                        hidden=hidden)
 
         if l == layers - 1:
             def write(t, h_new):
@@ -186,7 +190,7 @@ def _stack_kernel(x_ref, w0_ref, wr_ref, u_ref, b_ref, *rest, impl: str,
             def write(t, h_new):
                 seq_scr[t] = h_new
 
-        h, c = _layer_recurrence(zx, u_ref[l], su, table, write,
+        h, c = _layer_recurrence(zx_ref, u_ref[l], su, table, write,
                                  impl=impl, hidden=hidden, seq=seq, bb=bb)
         hn_ref[l] = h.astype(hn_ref.dtype)
         cn_ref[l] = c.astype(cn_ref.dtype)
@@ -256,6 +260,7 @@ def _lstm_seq_call(x, w, u, b, sw, su, *, impl: str, block_b: int,
             jax.ShapeDtypeStruct((pb, hidden), x.dtype),
             jax.ShapeDtypeStruct((pb, hidden), x.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((seq, bb, 4 * hidden), jnp.float32)],
         interpret=interpret,
     )(*operands)
     hs = hs.swapaxes(0, 1)[:bsz]
@@ -379,7 +384,8 @@ def _lstm_stack_call(x, w0, wr, us, bs, sws, sus, *, impl: str, block_b: int,
             jax.ShapeDtypeStruct((layers, pb, hidden), x.dtype),
             jax.ShapeDtypeStruct((layers, pb, hidden), x.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((seq, bb, hidden), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((seq, bb, hidden), jnp.float32),
+                        pltpu.VMEM((seq, bb, 4 * hidden), jnp.float32)],
         interpret=interpret,
     )(*operands)
     hs = hs.swapaxes(0, 1)[:bsz]

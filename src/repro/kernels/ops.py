@@ -2,7 +2,7 @@
 
 Execution mode is resolved per call by ``repro.kernels.runtime``: Mosaic on
 a real TPU backend, the interpreter elsewhere, overridable via
-``REPRO_PALLAS_INTERPRET`` (legacy alias ``REPRO_INTERPRET``). Setting the
+``REPRO_PALLAS_INTERPRET``. Setting the
 module attribute ``INTERPRET`` to a bool still force-overrides everything
 (back-compat escape hatch); leave it ``None`` for auto.
 

@@ -108,6 +108,7 @@ import math
 import numpy as np
 
 from repro.configs import get_reduced_config, list_archs
+from repro.kernels.runtime import enable_compile_cache
 from repro.core.workload import bursty_trace, irregular_trace, regular_trace
 from repro.serving.engine import InferenceEngine, ServeConfig, WorkloadAwareServer
 from repro.core.retry import RestartPolicy
@@ -160,7 +161,7 @@ def _make_stream(args, cfg, cal):
     return bursty_stream_for_service(cal, args.n, **kw)
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True, choices=list_archs())
     ap.add_argument("--mode", default="continuous",
@@ -265,6 +266,85 @@ def main(argv=None) -> int:
     ap.add_argument("--max-len", type=int, default=64)
     ap.add_argument("--new-tokens", type=int, default=8)
     ap.add_argument("--seed", type=int, default=0)
+    return ap
+
+
+def make_engine(args, cfg) -> InferenceEngine:
+    """The engine the launcher's flags describe, for model ``cfg``."""
+    if args.quant_weights:
+        cfg = dataclasses.replace(cfg, quant="int8")
+    return InferenceEngine(cfg, sc=ServeConfig(
+        max_batch=args.batch, max_len=args.max_len, spec_slack=_slack(args),
+        paged=args.paged, page_size=args.page_size,
+        num_pages=args.page_budget or None, share_prefix=args.share_prefix,
+        kv_quant="int8" if args.quant_kv else None,
+        energy_budget_j=args.energy_budget or None,
+        budget_window_s=args.budget_window))
+
+
+def _slack(args) -> int:
+    # paged pools need no spec_slack spare rows: verify-window tail blocks
+    # are allocated on demand out of the page pool
+    return (args.speculate_k
+            if args.mode in ("speculative", "compare") and not args.paged
+            else 0)
+
+
+def make_scheduler(args, engine: InferenceEngine, cal: EngineCalibration, *,
+                   prefill_chunk: int | None = None,
+                   speculate_k: int | None = None,
+                   preempt: bool = True) -> ContinuousBatchingScheduler:
+    """A scheduler over ``engine`` with the launcher's robustness, power and
+    (paged-only, when ``preempt``) preemption flags applied."""
+    faults = make_profile(args.fault_profile, seed=args.seed)
+    if args.power_faults:
+        therm = make_profile(args.power_faults, seed=args.seed)
+        if therm is not None:
+            # graft the thermal axis onto the base profile: one generator,
+            # one seed, so the composed run stays deterministic
+            faults = therm if faults is None else dataclasses.replace(
+                faults, therm_rate=therm.therm_rate,
+                therm_frac=therm.therm_frac, therm_ticks=therm.therm_ticks)
+    env = None
+    if args.power_cap > 0:
+        env = PowerEnvelope(caps=(CapWindow(0.0, math.inf, args.power_cap),))
+    retry = None
+    if args.retry_budget >= 0:
+        step = cal.step_s()
+        retry = RestartPolicy(max_restarts=args.retry_budget,
+                              backoff_s=2 * step, backoff_factor=2.0,
+                              max_backoff_s=64 * step)
+    # preempt/swap are paged-only scheduler knobs: compare mode's contiguous
+    # rows pass preempt=False
+    preempt_kw = ({"preempt": args.preempt_policy, "swap": args.swap}
+                  if preempt and args.preempt_policy != "none" else {})
+    return ContinuousBatchingScheduler(
+        engine, policy=args.policy, chips=args.chips, calibration=cal,
+        prefill_chunk=prefill_chunk, speculate_k=speculate_k,
+        shed=args.shed, queue_limit=args.queue_limit or None,
+        faults=faults if faults is not None and faults.enabled else None,
+        retry=retry, power=env,
+        brownout=None if args.brownout == "off" else args.brownout,
+        **preempt_kw)
+
+
+def build_server(args, cfg):
+    """(engine, calibration, scheduler) for the scheduler modes — the one
+    construction ``main`` and ``chip_smoke.py`` share."""
+    engine = make_engine(args, cfg)
+    cal = EngineCalibration(engine)
+    # time the decode step on its probe pool before the scheduler allocates
+    # the serving pool, so the two never hold device memory at once
+    cal.step_s()
+    sched = make_scheduler(
+        args, engine, cal,
+        prefill_chunk=args.prefill_chunk if args.mode == "chunked" else None,
+        speculate_k=args.speculate_k if args.mode == "speculative" else None)
+    return engine, cal, sched
+
+
+def main(argv=None) -> int:
+    ap = build_parser()
     args = ap.parse_args(argv)
     if args.preempt_policy != "none" and not args.paged:
         ap.error("--preempt-policy requires --paged")
@@ -276,29 +356,11 @@ def main(argv=None) -> int:
                                        or args.energy_budget > 0):
         ap.error("--brownout needs a power constraint: --power-cap, "
                  "--power-faults, or --energy-budget")
-
+    enable_compile_cache()
     cfg = get_reduced_config(args.arch)
-    if args.quant_weights:
-        cfg = dataclasses.replace(cfg, quant="int8")
-    # paged pools need no spec_slack spare rows: verify-window tail blocks
-    # are allocated on demand out of the page pool
-    slack = (args.speculate_k
-             if args.mode in ("speculative", "compare") and not args.paged
-             else 0)
-    engine = InferenceEngine(cfg, sc=ServeConfig(max_batch=args.batch,
-                                                 max_len=args.max_len,
-                                                 spec_slack=slack,
-                                                 paged=args.paged,
-                                                 page_size=args.page_size,
-                                                 num_pages=args.page_budget or None,
-                                                 share_prefix=args.share_prefix,
-                                                 kv_quant="int8" if args.quant_kv
-                                                 else None,
-                                                 energy_budget_j=(
-                                                     args.energy_budget or None),
-                                                 budget_window_s=args.budget_window))
 
     if args.mode == "strategies":
+        engine = make_engine(args, cfg)
         server = WorkloadAwareServer(engine, chips=args.chips)
         t_inf = server.measure_latency(batch=args.batch, new_tokens=args.new_tokens)
         prof = server.profile(t_inf)
@@ -320,43 +382,11 @@ def main(argv=None) -> int:
                   f"missed={v.missed}{star}")
         return 0
 
-    cal = EngineCalibration(engine)
+    engine, cal, sched = build_server(args, cfg)
+    cfg = engine.cfg
     reqs = _make_stream(args, cfg, cal)
     print(f"{args.arch}: {args.load} stream, {args.n} requests, "
           f"t_step={cal.step_s() * 1e3:.2f} ms, pool={args.batch}")
-    faults = make_profile(args.fault_profile, seed=args.seed)
-    if args.power_faults:
-        therm = make_profile(args.power_faults, seed=args.seed)
-        if therm is not None:
-            # graft the thermal axis onto the base profile: one generator,
-            # one seed, so the composed run stays deterministic
-            faults = therm if faults is None else dataclasses.replace(
-                faults, therm_rate=therm.therm_rate,
-                therm_frac=therm.therm_frac, therm_ticks=therm.therm_ticks)
-    env = None
-    if args.power_cap > 0:
-        env = PowerEnvelope(caps=(CapWindow(0.0, math.inf, args.power_cap),))
-    retry = None
-    if args.retry_budget >= 0:
-        step = cal.step_s()
-        retry = RestartPolicy(max_restarts=args.retry_budget,
-                              backoff_s=2 * step, backoff_factor=2.0,
-                              max_backoff_s=64 * step)
-    robust = dict(shed=args.shed,
-                  queue_limit=args.queue_limit or None,
-                  faults=faults if faults is not None and faults.enabled else None,
-                  retry=retry,
-                  power=env,
-                  brownout=None if args.brownout == "off" else args.brownout)
-    # preempt/swap are paged-only scheduler knobs; keep them out of `robust`
-    # so compare mode's contiguous rows stay valid
-    preempt_kw = ({"preempt": args.preempt_policy, "swap": args.swap}
-                  if args.preempt_policy != "none" else {})
-    sched = ContinuousBatchingScheduler(
-        engine, policy=args.policy, chips=args.chips, calibration=cal,
-        prefill_chunk=args.prefill_chunk if args.mode == "chunked" else None,
-        speculate_k=args.speculate_k if args.mode == "speculative" else None,
-        **robust, **preempt_kw)
     rep = sched.run(reqs)
     print("  " + rep.summary())
     tau = sched.policy.tau
@@ -364,13 +394,11 @@ def main(argv=None) -> int:
         print(f"  online tau after run: {tau:.3f} s "
               f"(refits: {getattr(sched.policy, 'refits', 0)})")
     if args.mode == "compare":
-        chkd = ContinuousBatchingScheduler(
-            engine, policy=args.policy, chips=args.chips, calibration=cal,
-            prefill_chunk=args.prefill_chunk, **robust).run(reqs)
+        chkd = make_scheduler(args, engine, cal, prefill_chunk=args.prefill_chunk,
+                              preempt=False).run(reqs)
         print("  " + chkd.summary())
-        spec = ContinuousBatchingScheduler(
-            engine, policy=args.policy, chips=args.chips, calibration=cal,
-            speculate_k=args.speculate_k, **robust).run(reqs)
+        spec = make_scheduler(args, engine, cal, speculate_k=args.speculate_k,
+                              preempt=False).run(reqs)
         print("  " + spec.summary())
         stat = run_static_batches(engine, reqs, policy=args.policy,
                                   chips=args.chips, calibration=cal,
@@ -382,14 +410,12 @@ def main(argv=None) -> int:
             peng = InferenceEngine(cfg, params=engine.params, sc=ServeConfig(
                 max_batch=args.batch, max_len=args.max_len, paged=True,
                 page_size=args.page_size, share_prefix=args.share_prefix))
-            psched = ContinuousBatchingScheduler(
-                peng, policy=args.policy, chips=args.chips, calibration=cal,
-                **robust, **preempt_kw)
+            psched = make_scheduler(args, peng, cal)
             prep = psched.run(reqs)
             print("  " + prep.summary() + " [paged]")
         pool = psched.pool
         contig_b = cache_bytes(cfg, batch=args.batch,
-                               max_len=args.max_len + slack)
+                               max_len=args.max_len + _slack(args))
         paged_b = paged_cache_bytes(cfg, batch=args.batch,
                                     num_pages=pool.num_pages,
                                     page_size=pool.page,
@@ -410,7 +436,6 @@ def main(argv=None) -> int:
               f"chunked/blocking p99 speedup: {rep.p99_s / chkd.p99_s:.2f}x, "
               f"speculative accepted/tick: {spec.accepted_per_tick:.2f}")
     return 0
-
 
 if __name__ == "__main__":
     raise SystemExit(main())

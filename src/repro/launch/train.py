@@ -30,6 +30,7 @@ import jax
 from repro.configs import SHAPES, get_config, get_reduced_config, list_archs
 from repro.core.cost_model import MeshPlan, bytes_per_device_estimate, estimate_step
 from repro.data.pipeline import SyntheticLM
+from repro.kernels.runtime import CACHE_DIR, enable_compile_cache
 from repro.training.train_loop import Trainer, TrainerConfig
 
 
@@ -102,10 +103,11 @@ def main(argv=None) -> int:
                     help="sequence length (default: 128, or the paper "
                          "workload's 28 under --paper-lstm)")
     ap.add_argument("--accum", type=int, default=1)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_train_ckpt")
+    ap.add_argument("--ckpt-dir", default=str(CACHE_DIR / "train_ckpt"))
     ap.add_argument("--paper-lstm", action="store_true",
                     help="plan the paper LSTM workload on the TPU kernel mapping")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.paper_lstm:
         plan_paper_lstm(args.batch, args.seq or 0)

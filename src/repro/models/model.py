@@ -140,18 +140,20 @@ def run_stack_prefill(stack, x, body, cfg: ArchConfig):
 
 
 def run_stack_decode(stack, caches, x, body, pos, cfg: ArchConfig):
-    """body(p, x, cache, pos) -> (x, cache). caches: stacked pytree."""
+    """body(p, x, cache, pos) -> (x, cache). caches: stacked tuple whose
+    elements may be ``PagedRows``: those are gathered one layer at a time
+    and come back as the layer's written blocks."""
 
     def f(x, inp):
         p, cache = inp
-        x, cache = body(p, x, cache, pos)
-        return x, cache
+        x, new = body(p, x, tuple(map(_cache_view, cache)), pos)
+        return x, tuple(_cache_written(n, c, pos) for n, c in zip(new, cache))
 
     if cfg.scan_layers:
         return jax.lax.scan(f, x, (stack, caches))
     outs = []
     for i in range(_stack_len(stack)):
-        x, c = body(_layer(stack, i), x, _layer(caches, i), pos)
+        x, c = f(x, (_layer(stack, i), _layer(caches, i)))
         outs.append(c)
     return x, jax.tree.map(lambda *ts: jnp.stack(ts), *outs)
 
@@ -437,12 +439,13 @@ def decode_step(params, cache, token, pos, cfg: ArchConfig):
         x0 = x
         convs, states, sks, svs = [], [], [], []
         for i, (start, length) in enumerate(_hybrid_segments(cfg)):
+            ck, cv = cache["shared_k"][i], cache["shared_v"][i]
             x, sk, sv = T.shared_attn_decode(
-                params["shared"], x, x0,
-                cache["shared_k"][i], cache["shared_v"][i], pos, cfg,
+                params["shared"], x, x0, _cache_view(ck), _cache_view(cv),
+                pos, cfg,
             )
-            sks.append(sk)
-            svs.append(sv)
+            sks.append(_cache_written(sk, ck, pos))
+            svs.append(_cache_written(sv, cv, pos))
             seg = _stack_slice(params["blocks"], start, length)
             segc = (
                 jax.lax.slice_in_dim(cache["conv"], start, start + length, axis=0),
@@ -545,12 +548,13 @@ def _chunk_forward(params, cache, tokens, pos, cfg: ArchConfig, frontend_embeds=
         x0 = x
         convs, states, sks, svs = [], [], [], []
         for i, (start, length) in enumerate(_hybrid_segments(cfg)):
+            ck, cv = cache["shared_k"][i], cache["shared_v"][i]
             x, sk, sv = T.shared_attn_chunk(
-                params["shared"], x, x0,
-                cache["shared_k"][i], cache["shared_v"][i], pos, cfg,
+                params["shared"], x, x0, _cache_view(ck), _cache_view(cv),
+                pos, cfg,
             )
-            sks.append(sk)
-            svs.append(sv)
+            sks.append(_cache_written(sk, ck, pos))
+            svs.append(_cache_written(sv, cv, pos))
             seg = _stack_slice(params["blocks"], start, length)
             segc = (
                 jax.lax.slice_in_dim(cache["conv"], start, start + length, axis=0),
@@ -654,15 +658,62 @@ def _mask_pad_logits(logits, cfg: ArchConfig):
 # for a scatter by page id. Rows gathered from unmapped blocks (the scratch
 # page) are garbage, but the positional masks select NEG_INF for every
 # position > pos before the softmax, so they are exactly inert in f32.
+#
+# The gather happens inside the layer loop, one layer at a time: a whole-
+# stack virtual row per slot would hold max_batch x layers x max_len rows at
+# once (8 GiB of temporaries for granite-3-8b at 16 layers, 16 slots and
+# 4k rows, which does not fit a 16 GiB chip next to the weights and pages).
 
 
-def paged_virtual_cache(pages, table_row):
-    """Gather one slot's virtual contiguous cache row.
+@jax.tree_util.register_pytree_node_class
+class PagedRows:
+    """One slot's view of a paged cache leaf, for the decode/verify bodies.
 
-    pages: (lead, num_pages, page_size, *tail); table_row: (max_blocks,)
-    int32 → (lead, max_blocks * page_size, *tail)."""
-    g = jnp.take(pages, table_row, axis=1)  # (lead, max_blocks, page, *tail)
-    return g.reshape(g.shape[0], g.shape[1] * g.shape[2], *g.shape[3:])
+    pages: (lead, num_pages, page, *tail) physical pages (int8 payloads
+    under ``kv_quant``, with their f32 ``scales`` pages; else None);
+    table: (lead, max_blocks) — the slot's page-table row repeated along
+    ``lead``, so a layer scan slices it together with the pages.
+    ``n_blocks`` whole blocks from the block holding ``pos`` are what the
+    step writes back (1 for decode, the verify window's span for verify).
+    """
+
+    def __init__(self, pages, scales, table, *, n_blocks: int, page: int):
+        self.pages, self.scales, self.table = pages, scales, table
+        self.n_blocks, self.page = n_blocks, page
+
+    def tree_flatten(self):
+        return (self.pages, self.scales, self.table), (self.n_blocks, self.page)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children, n_blocks=aux[0], page=aux[1])
+
+    def __getitem__(self, idx):
+        """Slice the ``lead`` axis (deepseek's dense/MoE split, hybrid's
+        per-application shared attention)."""
+        return jax.tree.map(lambda t: t[idx], self)
+
+    def gather(self):
+        """One layer's virtual contiguous row: (1, max_blocks * page, *tail)."""
+        g = jnp.take(self.pages, self.table, axis=0)  # (max_blocks, page, *tail)
+        if self.scales is not None:
+            from repro.serving.kv_cache import dequantize_kv
+
+            g = dequantize_kv(g, jnp.take(self.scales, self.table, axis=0))
+        return g.reshape(1, -1, *g.shape[2:])
+
+    def written(self, row, pos):
+        """The step's written blocks of an updated row: (n_blocks, page, *tail)."""
+        return paged_written_blocks(row, pos // self.page, self.n_blocks,
+                                    self.page)[:, 0]
+
+
+def _cache_view(c):
+    return c.gather() if isinstance(c, PagedRows) else c
+
+
+def _cache_written(new, c, pos):
+    return c.written(new, pos) if isinstance(c, PagedRows) else new
 
 
 def paged_written_blocks(row, first_blk, n_blocks, page_size):

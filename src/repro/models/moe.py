@@ -33,7 +33,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ArchConfig
 from repro.models.params import ParamDef
 from repro.models.quant import qeinsum
-from repro.sharding.compat import shard_map
 from repro.sharding.rules import active_mesh, batch_axes
 
 
@@ -280,7 +279,7 @@ def moe_apply(params, x, cfg: ArchConfig):
 
     fn = partial(_moe_sharded_body, cfg=cfg, mesh=mesh, ep_axes=ep_axes,
                  mode=mode, tp_split=tp_split)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(pspec, x_spec),
         out_specs=(x_spec, P()),

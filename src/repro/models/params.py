@@ -15,6 +15,7 @@ This is the MaxText-style "logical axis annotation" pattern, kept minimal.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable
 
@@ -38,7 +39,10 @@ class ParamDef:
         assert len(self.shape) == len(self.logical), (self.shape, self.logical)
 
 
+@functools.partial(jax.jit, static_argnums=1)
 def _initialize(key: jax.Array, d: ParamDef) -> jax.Array:
+    """Draw one leaf. Jitted, so the f32 draw fuses into the cast and the
+    device never holds a full-size f32 copy of a bf16 leaf."""
     if d.init == "zeros":
         return jnp.zeros(d.shape, d.dtype)
     if d.init == "ones":

@@ -35,16 +35,14 @@ from repro.models.model import (
     decode_verify,
     encoder_cross_cache,
     init_model,
-    paged_virtual_cache,
-    paged_written_blocks,
+    PagedRows,
     prefill,
     prefill_chunk,
     verify_block_span,
 )
 from repro.models.params import init_params
 from repro.serving.faults import FaultProfile
-from repro.serving.kv_cache import (cache_defs, dequantize_kv, paged_keys,
-                                    quantize_kv)
+from repro.serving.kv_cache import cache_defs, paged_keys, quantize_kv
 from repro.serving.pages import PagedSlotPool
 from repro.serving.slots import SlotPool, grow_cache
 
@@ -295,10 +293,25 @@ class InferenceEngine:
         return jax.vmap(one, in_axes=(1, 0, 0), out_axes=((0, 0), 1))(
             cache, tok, pos)
 
+    def _paged_rows(self, cache, table_row, n_blocks):
+        """One slot's ``PagedRows`` view of each paged cache leaf (the model
+        gathers them a layer at a time)."""
+        pkeys = paged_keys(self.cfg)
+        quant = self.sc.kv_quant
+        views = {}
+        for k in pkeys:
+            pages = cache[k]
+            views[k] = PagedRows(
+                pages, cache[f"{k}_scale"] if quant else None,
+                jnp.broadcast_to(table_row, (pages.shape[0], *table_row.shape)),
+                n_blocks=n_blocks, page=self.sc.page_size)
+        return views
+
     def _paged_decode_impl(self, params, cache, tok, pos, active, table):
-        """Paged twin of ``_masked_decode_impl``: gather each slot's virtual
-        contiguous row through its table row, run the identical per-slot
-        decode body, scatter the written block back by page id.
+        """Paged twin of ``_masked_decode_impl``: each slot's cache row is
+        gathered through its table row, one layer at a time inside the
+        model's layer loop (``PagedRows``), the identical per-slot decode
+        body runs, and the written block is scattered back by page id.
 
         Rows gathered from unmapped blocks (scratch) are garbage, but every
         position > pos is masked to NEG_INF before the softmax, so they are
@@ -309,7 +322,7 @@ class InferenceEngine:
         their "{key}_scale" pages) and the written block is re-quantized
         before the scatter; re-quantizing the block's untouched rows is
         idempotent, so only the freshly written position changes."""
-        cfg, page = self.cfg, self.sc.page_size
+        cfg = self.cfg
         pkeys = paged_keys(cfg)
         quant = self.sc.kv_quant
         skeys = tuple(f"{k}_scale" for k in pkeys) if quant else ()
@@ -318,37 +331,28 @@ class InferenceEngine:
         pos = jnp.where(active, pos, 0)
 
         def one(rest_b, tok_b, pos_b, tab_b, act_b):
-            if quant:
-                virt = {k: dequantize_kv(
-                    paged_virtual_cache(paged[k], tab_b),
-                    paged_virtual_cache(paged[f"{k}_scale"], tab_b))
-                    for k in pkeys}
-            else:
-                virt = {k: paged_virtual_cache(paged[k], tab_b) for k in pkeys}
-            c1 = jax.tree.map(lambda t: jnp.expand_dims(t, 1),
-                              {**rest_b, **virt})
+            c1 = {**jax.tree.map(lambda t: jnp.expand_dims(t, 1), rest_b),
+                  **self._paged_rows(paged, tab_b, 1)}
             logits, c1 = decode_step(params, c1, tok_b[None, None], pos_b, cfg)
-            c1 = jax.tree.map(lambda t: jnp.squeeze(t, 1), c1)
             v = logits[0, : cfg.vocab_size]
             nxt = jnp.argmax(v).astype(jnp.int32)
             fin = jnp.isfinite(v).all()
-            blk = pos_b // page
             written = {}
             for k in pkeys:
-                w = paged_written_blocks(c1[k], blk, 1, page)[0]
+                w = c1[k][:, 0]  # (lead, page, *tail): the one written block
                 if quant:
                     written[k], written[f"{k}_scale"] = quantize_kv(w)
                 else:
                     written[k] = w
-            pid = jnp.where(act_b, jnp.take(tab_b, blk), 0)
-            return (nxt, fin, written, pid), {k: c1[k] for k in rest}
+            pid = jnp.where(act_b, jnp.take(tab_b, pos_b // self.sc.page_size), 0)
+            return (nxt, fin, written, pid), {
+                k: jnp.squeeze(c1[k], 1) for k in rest}
 
         (nxt, fin, written, pids), rest1 = jax.vmap(
             one, in_axes=(1, 0, 0, 0, 0), out_axes=((0, 0, 0, 0), 1))(
             rest, tok, pos, table, active)
         for k in paged:
-            paged[k] = paged[k].at[:, pids].set(
-                jnp.moveaxis(written[k], 0, 1))
+            paged[k] = paged[k].at[:, pids].set(jnp.moveaxis(written[k], 0, 1))
         return (nxt, fin), {**rest1, **paged}
 
     # -- fault injection ------------------------------------------------------
@@ -490,7 +494,8 @@ class InferenceEngine:
             cache, tokens, pos)
 
     def _paged_verify_impl(self, params, cache, tok, drafts, pos, active, table):
-        """Paged twin of ``_masked_verify_impl``: gather, verify, scatter.
+        """Paged twin of ``_masked_verify_impl``: gather (a layer at a time),
+        verify, scatter.
 
         A K+1 window can straddle up to ``verify_block_span`` blocks; all of
         them are extracted, and blocks past the slot's last written block —
@@ -514,15 +519,8 @@ class InferenceEngine:
         mb = table.shape[1]
 
         def one(rest_b, toks_b, pos_b, tab_b, act_b):
-            if quant:
-                virt = {k: dequantize_kv(
-                    paged_virtual_cache(paged[k], tab_b),
-                    paged_virtual_cache(paged[f"{k}_scale"], tab_b))
-                    for k in pkeys}
-            else:
-                virt = {k: paged_virtual_cache(paged[k], tab_b) for k in pkeys}
-            c1 = jax.tree.map(lambda t: jnp.expand_dims(t, 1),
-                              {**rest_b, **virt})
+            c1 = {**jax.tree.map(lambda t: jnp.expand_dims(t, 1), rest_b),
+                  **self._paged_rows(paged, tab_b, nw)}
             logits, c1 = decode_verify(params, c1, toks_b[None, :], pos_b, cfg)
             v = logits[0, :, : cfg.vocab_size]
             g = jnp.argmax(v, axis=-1).astype(jnp.int32)
@@ -530,12 +528,11 @@ class InferenceEngine:
             ok = jnp.cumprod((toks_b[1:] == g[:-1]).astype(jnp.int32))
             a = jnp.sum(ok).astype(jnp.int32)
             c1 = commit_verify(c1, a, cfg)
-            c1 = jax.tree.map(lambda t: jnp.squeeze(t, 1), c1)
             first_blk = pos_b // page
             last_blk = (pos_b + w - 1) // page
             written = {}
             for k in pkeys:
-                wb = paged_written_blocks(c1[k], first_blk, nw, page)
+                wb = c1[k]  # (lead, nw, page, *tail): the window's blocks
                 if quant:
                     written[k], written[f"{k}_scale"] = quantize_kv(wb)
                 else:
@@ -544,15 +541,15 @@ class InferenceEngine:
             valid = act_b & (blks <= last_blk)
             pids = jnp.where(valid,
                              jnp.take(tab_b, jnp.minimum(blks, mb - 1)), 0)
-            return (g, a, fin, written, pids), {k: c1[k] for k in rest}
+            return (g, a, fin, written, pids), {
+                k: jnp.squeeze(c1[k], 1) for k in rest}
 
         (g, a, fin, written, pids), rest1 = jax.vmap(
             one, in_axes=(1, 0, 0, 0, 0), out_axes=((0, 0, 0, 0, 0), 1))(
             rest, tokens, pos, table, active)
         flat = pids.reshape(-1)  # (B * nw,) — duplicates only ever hit scratch
         for k in paged:
-            wr = written[k]  # (B, nw, lead, page, *tail)
-            wr = jnp.moveaxis(wr, 2, 0)  # (lead, B, nw, page, *tail)
+            wr = jnp.moveaxis(written[k], 1, 0)  # (lead, B, nw, page, *tail)
             wr = wr.reshape(wr.shape[0], -1, page, *wr.shape[4:])
             paged[k] = paged[k].at[:, flat].set(wr)
         return (g, a, fin), {**rest1, **paged}
@@ -625,13 +622,14 @@ class InferenceEngine:
         calibration timing. Uses a non-donating twin of the chunk jit so the
         probe cache can be reused across timing repeats; the step's cost is
         position-independent (attention always spans the whole cache
-        capacity, dead rows are masked, not skipped)."""
+        capacity, dead rows are masked, not skipped). It returns only the
+        logits, so no second group-sized cache is ever materialized."""
         if self._chunk_probe_fn is None:
             cfg = self.cfg
             self._chunk_probe_fn = jax.jit(
                 lambda p, cache, toks, pos, fe: prefill_chunk(
                     p, cache, toks, pos, cfg, frontend_embeds=fe
-                )
+                )[0]
             )
         cache = init_params(
             cache_defs(self.cfg, batch=batch, max_len=self.capacity),
@@ -640,7 +638,7 @@ class InferenceEngine:
         toks = jnp.zeros((batch, chunk_tokens), jnp.int32)
         fe = self._chunk_frontend(batch)
         return lambda: self._chunk_probe_fn(self.params, cache, toks,
-                                            jnp.int32(0), fe)[0]
+                                            jnp.int32(0), fe)
 
     def chunked_prefill_step(self, st: "ChunkedPrefillState",
                              chunk_tokens: int) -> int:

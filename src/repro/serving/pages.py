@@ -17,8 +17,8 @@ sequence positions are split into logical blocks of ``page_size`` rows:
 ``table`` is a dense int32 ``(max_batch, max_blocks)`` array passed INTO the
 decode/verify jits, so the paged paths keep ONE compile signature — the
 per-slot attention bodies gather their virtual contiguous cache row through
-the table (``models.model.paged_virtual_cache``) and the written blocks are
-scattered back by page id afterwards. Page index 0 is a reserved SCRATCH
+the table, one layer at a time (``models.model.PagedRows``), and the
+written blocks are scattered back by page id afterwards. Page index 0 is a reserved SCRATCH
 page: unmapped table entries point at it, so gathers of never-written
 blocks read garbage that the engine's positional masks keep inert, and
 writes from inactive slots or invalid verify-window blocks are redirected

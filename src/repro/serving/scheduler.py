@@ -203,6 +203,9 @@ class EngineCalibration:
             pool = eng.make_pool()
             pool.active[:] = True  # full occupancy; positions stay at 0
             self._step = self._time(lambda: eng.masked_decode_step(pool))
+            # free the probe pool's device cache now: the pool's jits refer
+            # back to it, so otherwise only the cycle collector would
+            pool.cache = None
         return self._step
 
     def verify_s(self, k: int) -> float:
@@ -215,6 +218,7 @@ class EngineCalibration:
             drafts = np.zeros((pool.max_batch, k), np.int32)
             self._verify[k] = self._time(
                 lambda: eng.masked_speculative_step(pool, drafts))
+            pool.cache = None  # as in step_s
         return self._verify[k]
 
 

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.sharding.compat import shard_map
 from repro.sharding.rules import batch_axes
 
 
@@ -87,7 +86,7 @@ def dp_value_and_grad(
         return loss, grads
 
     out_specs = (P(), P(), P()) if has_aux else (P(), P())
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P(dp_spec)),

@@ -4,6 +4,8 @@ import os
 # 512 host devices, in its own process. Keep any inherited flag out.
 os.environ.pop("XLA_FLAGS", None)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# no persistent compilation cache in tests, whatever an entry point enables
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 try:
     from hypothesis import HealthCheck, settings

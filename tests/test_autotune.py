@@ -263,10 +263,11 @@ def test_default_interpret_env_override(monkeypatch):
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
     assert runtime.default_interpret() is True
     monkeypatch.delenv("REPRO_PALLAS_INTERPRET")
+    # the retired REPRO_INTERPRET alias no longer steers anything
     monkeypatch.setenv("REPRO_INTERPRET", "false")
-    assert runtime.default_interpret() is False
+    assert runtime.default_interpret() is True
     monkeypatch.delenv("REPRO_INTERPRET")
-    # no env: CPU container has no TPU → interpret
+    # no env: a CPU backend has no TPU → interpret
     assert runtime.default_interpret() is True
     assert runtime.resolve_interpret(None) is True
     assert runtime.resolve_interpret(False) is False
