@@ -1,0 +1,7 @@
+"""Device milliseconds per decode step: the decode program's time on the
+device in the trace, over its runs."""
+
+
+def read(run):
+    k = run.trace and run.trace["kinds"]["decode"]
+    return k["device_s"] / k["calls"] * 1e3 if k and k["calls"] else None
