@@ -164,20 +164,21 @@ def attention_decode(q, k_cache, v_cache, pos) -> jax.Array:
     collectives left are the softmax partials and the (B,1,H,D) output
     all-reduce.
     """
-    b, _, h, d = q.shape
-    kvh = k_cache.shape[2]
-    g = h // kvh
-    qf = q.astype(jnp.float32)
-    k = constrain(_repeat_kv(k_cache, g), ("batch", "kv_seq", None, None))
-    v = constrain(_repeat_kv(v_cache, g), ("batch", "kv_seq", None, None))
-    s = jnp.einsum("bqhd,bkhd->bhqk", qf, k.astype(jnp.float32))
-    s = constrain(s / jnp.sqrt(d), ("batch", None, None, "kv_seq"))
-    valid = jnp.arange(k_cache.shape[1]) <= pos
-    s = jnp.where(valid[None, None, None, :], s, NEG_INF)
-    p = constrain(jax.nn.softmax(s, axis=-1), ("batch", None, None, "kv_seq"))
-    out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
-    out = constrain(out, ("batch", None, None, None))
-    return out.astype(q.dtype)
+    with jax.named_scope("attention"):
+        b, _, h, d = q.shape
+        kvh = k_cache.shape[2]
+        g = h // kvh
+        qf = q.astype(jnp.float32)
+        k = constrain(_repeat_kv(k_cache, g), ("batch", "kv_seq", None, None))
+        v = constrain(_repeat_kv(v_cache, g), ("batch", "kv_seq", None, None))
+        s = jnp.einsum("bqhd,bkhd->bhqk", qf, k.astype(jnp.float32))
+        s = constrain(s / jnp.sqrt(d), ("batch", None, None, "kv_seq"))
+        valid = jnp.arange(k_cache.shape[1]) <= pos
+        s = jnp.where(valid[None, None, None, :], s, NEG_INF)
+        p = constrain(jax.nn.softmax(s, axis=-1), ("batch", None, None, "kv_seq"))
+        out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
+        out = constrain(out, ("batch", None, None, None))
+        return out.astype(q.dtype)
 
 
 def run_attention(cfg: ArchConfig, q, k, v, *, causal: bool) -> jax.Array:
@@ -185,9 +186,10 @@ def run_attention(cfg: ArchConfig, q, k, v, *, causal: bool) -> jax.Array:
     sq = q.shape[1]
     if impl == "auto":
         impl = "chunked" if sq > 2 * cfg.attn_chunk else "naive"
-    if impl == "chunked":
-        return attention_chunked(q, k, v, causal=causal, chunk=cfg.attn_chunk)
-    return attention_naive(q, k, v, causal=causal)
+    with jax.named_scope("attention"):
+        if impl == "chunked":
+            return attention_chunked(q, k, v, causal=causal, chunk=cfg.attn_chunk)
+        return attention_naive(q, k, v, causal=causal)
 
 
 # ---------------------------------------------------------------------------
@@ -210,20 +212,21 @@ def gqa_defs(cfg: ArchConfig, *, cross: bool = False) -> dict:
 
 
 def gqa_project_qkv(params, x, cfg: ArchConfig, positions, *, rope: bool = True):
-    q = qeinsum("bsd,dhe->bshe", x, params["wq"])
-    k = qeinsum("bsd,dhe->bshe", x, params["wk"])
-    v = qeinsum("bsd,dhe->bshe", x, params["wv"])
-    if cfg.qkv_bias:
-        q = q + params["bq"]
-        k = k + params["bk"]
-        v = v + params["bv"]
-    if rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-    q = constrain(q, ("batch", None, "heads", None))
-    k = constrain(k, ("batch", None, "kv_heads", None))
-    v = constrain(v, ("batch", None, "kv_heads", None))
-    return q, k, v
+    with jax.named_scope("proj"):
+        q = qeinsum("bsd,dhe->bshe", x, params["wq"])
+        k = qeinsum("bsd,dhe->bshe", x, params["wk"])
+        v = qeinsum("bsd,dhe->bshe", x, params["wv"])
+        if cfg.qkv_bias:
+            q = q + params["bq"]
+            k = k + params["bk"]
+            v = v + params["bv"]
+        if rope:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
+        q = constrain(q, ("batch", None, "heads", None))
+        k = constrain(k, ("batch", None, "kv_heads", None))
+        v = constrain(v, ("batch", None, "kv_heads", None))
+        return q, k, v
 
 
 def gqa_apply(params, x, cfg: ArchConfig, *, causal: bool = True, rope: bool = True):
@@ -260,11 +263,12 @@ def write_cache(cache, new, pos, cfg: ArchConfig, axis: int = 1):
                HBM; wins whenever the cache shard ≪ ICI copy (hillclimb H1
                of the decode cell, EXPERIMENTS.md §Perf).
     """
-    new = new.astype(cache.dtype)
-    if cfg.cache_update == "onehot":
-        mask = jax.lax.broadcasted_iota(jnp.int32, cache.shape, axis) == pos
-        return jnp.where(mask, jnp.broadcast_to(new, cache.shape), cache)
-    return jax.lax.dynamic_update_slice_in_dim(cache, new, pos, axis=axis)
+    with jax.named_scope("kv_pages"):
+        new = new.astype(cache.dtype)
+        if cfg.cache_update == "onehot":
+            mask = jax.lax.broadcasted_iota(jnp.int32, cache.shape, axis) == pos
+            return jnp.where(mask, jnp.broadcast_to(new, cache.shape), cache)
+        return jax.lax.dynamic_update_slice_in_dim(cache, new, pos, axis=axis)
 
 
 def write_cache_span(cache, new, pos, axis: int = 1):
@@ -273,9 +277,10 @@ def write_cache_span(cache, new, pos, axis: int = 1):
     The chunked-prefill path always uses dynamic_update_slice: chunk writes
     are a host-driven serving flow over a pool-resident cache, not the
     TP-sharded decode step that needs the onehot variant."""
-    return jax.lax.dynamic_update_slice_in_dim(
-        cache, new.astype(cache.dtype), pos, axis=axis
-    )
+    with jax.named_scope("kv_pages"):
+        return jax.lax.dynamic_update_slice_in_dim(
+            cache, new.astype(cache.dtype), pos, axis=axis
+        )
 
 
 def attention_chunk(q, k_cache, v_cache, pos) -> jax.Array:
@@ -292,19 +297,20 @@ def attention_chunk(q, k_cache, v_cache, pos) -> jax.Array:
     never see them and its writes overwrite them — acceptance only moves
     the slot's position, no cache surgery (``models.model.decode_verify``).
     """
-    b, t, h, d = q.shape
-    kvh = k_cache.shape[2]
-    g = h // kvh
-    qf = q.astype(jnp.float32)
-    k = _repeat_kv(k_cache, g)
-    v = _repeat_kv(v_cache, g)
-    s = jnp.einsum("bqhd,bkhd->bhqk", qf, k.astype(jnp.float32)) / jnp.sqrt(d)
-    qpos = pos + jnp.arange(t)
-    valid = jnp.arange(k_cache.shape[1])[None, :] <= qpos[:, None]  # (T, Smax)
-    s = jnp.where(valid[None, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
-    return out.astype(q.dtype)
+    with jax.named_scope("attention"):
+        b, t, h, d = q.shape
+        kvh = k_cache.shape[2]
+        g = h // kvh
+        qf = q.astype(jnp.float32)
+        k = _repeat_kv(k_cache, g)
+        v = _repeat_kv(v_cache, g)
+        s = jnp.einsum("bqhd,bkhd->bhqk", qf, k.astype(jnp.float32)) / jnp.sqrt(d)
+        qpos = pos + jnp.arange(t)
+        valid = jnp.arange(k_cache.shape[1])[None, :] <= qpos[:, None]  # (T, Smax)
+        s = jnp.where(valid[None, None], s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
+        return out.astype(q.dtype)
 
 
 def gqa_chunk_apply(params, x, cache_k, cache_v, pos, cfg: ArchConfig, *, rope: bool = True):
@@ -317,7 +323,9 @@ def gqa_chunk_apply(params, x, cache_k, cache_v, pos, cfg: ArchConfig, *, rope: 
     k_cache = write_cache_span(cache_k, k_new, pos)
     v_cache = write_cache_span(cache_v, v_new, pos)
     out = attention_chunk(q, k_cache, v_cache, pos)
-    return qeinsum("bshe,hed->bsd", out, params["wo"]), k_cache, v_cache
+    with jax.named_scope("proj"):
+        out = qeinsum("bshe,hed->bsd", out, params["wo"])
+    return out, k_cache, v_cache
 
 
 def gqa_decode_apply(params, x, cache_k, cache_v, pos, cfg: ArchConfig, *, rope: bool = True):
@@ -340,7 +348,8 @@ def gqa_decode_apply(params, x, cache_k, cache_v, pos, cfg: ArchConfig, *, rope:
     k_cache = write_cache(cache_k, k_new, pos, cfg)
     v_cache = write_cache(cache_v, v_new, pos, cfg)
     out = attention_decode(q, k_cache, v_cache, pos)
-    out = qeinsum("bshe,hed->bsd", out, params["wo"])
+    with jax.named_scope("proj"):
+        out = qeinsum("bshe,hed->bsd", out, params["wo"])
     return out, k_cache, v_cache
 
 
@@ -491,17 +500,18 @@ def mlp_defs(cfg: ArchConfig, d_ff: int | None = None) -> dict:
 
 
 def mlp_apply(params, x, cfg: ArchConfig):
-    from repro.models.activations import get_activation
+    with jax.named_scope("mlp"):
+        from repro.models.activations import get_activation
 
-    act = get_activation(cfg.activation, cfg.activation_impl)
-    if "wi" in params:
-        h = qeinsum("bsd,df->bsf", x, params["wi"]) + params["bi"].astype(x.dtype)
-        h = constrain(act(h), ("batch", None, "mlp"))
-        return qeinsum("bsf,fd->bsd", h, params["wo"]) + params["bo"].astype(x.dtype)
-    g = qeinsum("bsd,df->bsf", x, params["wg"])
-    u = qeinsum("bsd,df->bsf", x, params["wu"])
-    h = constrain(act(g) * u, ("batch", None, "mlp"))
-    return qeinsum("bsf,fd->bsd", h, params["wd"])
+        act = get_activation(cfg.activation, cfg.activation_impl)
+        if "wi" in params:
+            h = qeinsum("bsd,df->bsf", x, params["wi"]) + params["bi"].astype(x.dtype)
+            h = constrain(act(h), ("batch", None, "mlp"))
+            return qeinsum("bsf,fd->bsd", h, params["wo"]) + params["bo"].astype(x.dtype)
+        g = qeinsum("bsd,df->bsf", x, params["wg"])
+        u = qeinsum("bsd,df->bsf", x, params["wu"])
+        h = constrain(act(g) * u, ("batch", None, "mlp"))
+        return qeinsum("bsf,fd->bsd", h, params["wd"])
 
 
 # ---------------------------------------------------------------------------

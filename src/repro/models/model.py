@@ -381,9 +381,11 @@ def prefill(params, tokens, cfg: ArchConfig, frontend_embeds=None):
         }
     else:
         raise ValueError(f)
-    hidden = T.apply_norm(cfg, params["final_norm"], x)
-    logits = unembed_apply(params["embed"], hidden[:, -1:], cfg)[:, 0]
-    return _mask_pad_logits(logits, cfg).astype(jnp.float32), cache
+    with jax.named_scope("logits"):
+        hidden = T.apply_norm(cfg, params["final_norm"], x)
+        logits = unembed_apply(params["embed"], hidden[:, -1:], cfg)[:, 0]
+        logits = _mask_pad_logits(logits, cfg).astype(jnp.float32)
+    return logits, cache
 
 
 # ---------------------------------------------------------------------------
@@ -464,9 +466,11 @@ def decode_step(params, cache, token, pos, cfg: ArchConfig):
         }
     else:
         raise ValueError(f)
-    hidden = T.apply_norm(cfg, params["final_norm"], x)
-    logits = unembed_apply(params["embed"], hidden, cfg)[:, 0]
-    return _mask_pad_logits(logits, cfg).astype(jnp.float32), cache
+    with jax.named_scope("logits"):
+        hidden = T.apply_norm(cfg, params["final_norm"], x)
+        logits = unembed_apply(params["embed"], hidden, cfg)[:, 0]
+        logits = _mask_pad_logits(logits, cfg).astype(jnp.float32)
+    return logits, cache
 
 
 # ---------------------------------------------------------------------------
@@ -591,9 +595,11 @@ def prefill_chunk(params, cache, tokens, pos, cfg: ArchConfig, frontend_embeds=N
     cache) — after the final chunk the logits match ``prefill``'s up to
     chunk-boundary float reassociation."""
     x, cache = _chunk_forward(params, cache, tokens, pos, cfg, frontend_embeds)
-    hidden = T.apply_norm(cfg, params["final_norm"], x)
-    logits = unembed_apply(params["embed"], hidden[:, -1:], cfg)[:, 0]
-    return _mask_pad_logits(logits, cfg).astype(jnp.float32), cache
+    with jax.named_scope("logits"):
+        hidden = T.apply_norm(cfg, params["final_norm"], x)
+        logits = unembed_apply(params["embed"], hidden[:, -1:], cfg)[:, 0]
+        logits = _mask_pad_logits(logits, cfg).astype(jnp.float32)
+    return logits, cache
 
 
 # ---------------------------------------------------------------------------
@@ -619,9 +625,11 @@ def decode_verify(params, cache, tokens, pos, cfg: ArchConfig, frontend_embeds=N
     """
     x, cache = _chunk_forward(params, cache, tokens, pos, cfg, frontend_embeds,
                               ssm_block=T.ssm_block_verify)
-    hidden = T.apply_norm(cfg, params["final_norm"], x)
-    logits = unembed_apply(params["embed"], hidden, cfg)
-    return _mask_pad_logits(logits, cfg).astype(jnp.float32), cache
+    with jax.named_scope("logits"):
+        hidden = T.apply_norm(cfg, params["final_norm"], x)
+        logits = unembed_apply(params["embed"], hidden, cfg)
+        logits = _mask_pad_logits(logits, cfg).astype(jnp.float32)
+    return logits, cache
 
 
 def commit_verify(cache, accepted, cfg: ArchConfig):
@@ -695,17 +703,19 @@ class PagedRows:
 
     def gather(self):
         """One layer's virtual contiguous row: (1, max_blocks * page, *tail)."""
-        g = jnp.take(self.pages, self.table, axis=0)  # (max_blocks, page, *tail)
-        if self.scales is not None:
-            from repro.serving.kv_cache import dequantize_kv
+        with jax.named_scope("kv_pages"):
+            g = jnp.take(self.pages, self.table, axis=0)  # (max_blocks, page, *tail)
+            if self.scales is not None:
+                from repro.serving.kv_cache import dequantize_kv
 
-            g = dequantize_kv(g, jnp.take(self.scales, self.table, axis=0))
-        return g.reshape(1, -1, *g.shape[2:])
+                g = dequantize_kv(g, jnp.take(self.scales, self.table, axis=0))
+            return g.reshape(1, -1, *g.shape[2:])
 
     def written(self, row, pos):
         """The step's written blocks of an updated row: (n_blocks, page, *tail)."""
-        return paged_written_blocks(row, pos // self.page, self.n_blocks,
-                                    self.page)[:, 0]
+        with jax.named_scope("kv_pages"):
+            return paged_written_blocks(row, pos // self.page, self.n_blocks,
+                                        self.page)[:, 0]
 
 
 def _cache_view(c):

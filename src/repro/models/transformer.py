@@ -101,24 +101,30 @@ def dense_block_prefill(p, x, cfg: ArchConfig):
     return x, (k, v)
 
 
+def _proj_norm(cfg: ArchConfig, p, x):
+    """A block's pre-attention or pre-MLP norm, in the ``proj`` scope."""
+    with jax.named_scope("proj"):
+        return apply_norm(cfg, p, x)
+
+
 def dense_block_chunk(p, x, cache, pos, cfg: ArchConfig):
     """Chunked-prefill body: T prompt tokens appended at ``pos``."""
     k_cache, v_cache = cache
     a, k_cache, v_cache = gqa_chunk_apply(
-        p["attn"], apply_norm(cfg, p["ln1"], x), k_cache, v_cache, pos, cfg
+        p["attn"], _proj_norm(cfg, p["ln1"], x), k_cache, v_cache, pos, cfg
     )
     x = x + a
-    x = x + mlp_apply(p["mlp"], apply_norm(cfg, p["ln2"], x), cfg)
+    x = x + mlp_apply(p["mlp"], _proj_norm(cfg, p["ln2"], x), cfg)
     return x, (k_cache, v_cache)
 
 
 def dense_block_decode(p, x, cache, pos, cfg: ArchConfig):
     k_cache, v_cache = cache
     a, k_cache, v_cache = gqa_decode_apply(
-        p["attn"], apply_norm(cfg, p["ln1"], x), k_cache, v_cache, pos, cfg
+        p["attn"], _proj_norm(cfg, p["ln1"], x), k_cache, v_cache, pos, cfg
     )
     x = x + a
-    x = x + mlp_apply(p["mlp"], apply_norm(cfg, p["ln2"], x), cfg)
+    x = x + mlp_apply(p["mlp"], _proj_norm(cfg, p["ln2"], x), cfg)
     return x, (k_cache, v_cache)
 
 

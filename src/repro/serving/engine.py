@@ -25,6 +25,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.core.energy import DEFAULT_CHIP, TPUChip
@@ -61,6 +62,13 @@ def tpu_reload_costs(cfg: ArchConfig, chip: TPUChip = DEFAULT_CHIP, *,
 # ---------------------------------------------------------------------------
 # Real execution engine
 # ---------------------------------------------------------------------------
+def _greedy(v):
+    """Greedy tokens of one slot's logits (..., vocab), and whether every
+    logit is finite (the finiteness guard)."""
+    with jax.named_scope("logits"):
+        return jnp.argmax(v, axis=-1).astype(jnp.int32), jnp.isfinite(v).all()
+
+
 @dataclasses.dataclass
 class ServeConfig:
     max_batch: int = 8
@@ -123,31 +131,28 @@ class InferenceEngine:
             from repro.models.quant import quantize_params
 
             self.params = quantize_params(self.params, cfg)
-        self._prefill = jax.jit(
-            lambda p, toks, fe: prefill(p, toks, cfg, frontend_embeds=fe)
-        )
+        # each program is a named function, so a profile shows it as
+        # jit_<name>; the chunk step below is the one still a lambda
+        self._prefill = jax.jit(self._prefill_impl)
         # the cache argument is donated: each decode step updates it in place
         # instead of doubling cache memory per step (no-op where the backend
         # lacks donation — the semantics are unchanged either way)
-        self._decode = jax.jit(
-            lambda p, cache, tok, pos: decode_step(p, cache, tok, pos, cfg),
-            donate_argnums=(1,),
-        )
+        self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
         self._masked_decode = jax.jit(self._masked_decode_impl, donate_argnums=(1,))
         # speculative verify: one donated jit, keyed on K by the drafts'
         # (max_batch, K) shape — a new K retraces, a fixed K reuses
         self._masked_verify = jax.jit(self._masked_verify_impl, donate_argnums=(1,))
         # chunked prefill: T prompt tokens appended to a full-capacity cache
-        # at a traced offset — one compile per (batch, chunk-length) signature
+        # at a traced offset — one compile per (batch, chunk-length) signature.
+        # Still a lambda, so a profile shows it as jit__lambda: the engine's
+        # last unnamed program
         self._chunk = jax.jit(
             lambda p, cache, toks, pos, fe: prefill_chunk(
                 p, cache, toks, pos, cfg, frontend_embeds=fe
             ),
             donate_argnums=(1,),
         )
-        self._cross_cache = jax.jit(
-            lambda p, fe: encoder_cross_cache(p, cfg, fe)
-        )
+        self._cross_cache = jax.jit(self._cross_cache_impl)
         self._chunk_probe_fn = None  # non-donating twin of _chunk (calibration)
         # fault injection: overwrite one slot's cache rows with NaN (the
         # slot index is traced, so all slots share one compile)
@@ -162,6 +167,15 @@ class InferenceEngine:
         # physical cache rows per slot: the admission bound plus the
         # speculative verify slack (see ServeConfig.spec_slack)
         self.capacity = self.sc.max_len + self.sc.spec_slack
+
+    def _prefill_impl(self, params, tokens, frontend):
+        return prefill(params, tokens, self.cfg, frontend_embeds=frontend)
+
+    def _decode_impl(self, params, cache, tok, pos):
+        return decode_step(params, cache, tok, pos, self.cfg)
+
+    def _cross_cache_impl(self, params, frontend):
+        return encoder_cross_cache(params, self.cfg, frontend)
 
     def _frontend_stub(self, batch: int):
         cfg = self.cfg
@@ -216,13 +230,16 @@ class InferenceEngine:
         if s0 + budget > self.sc.max_len:
             raise ValueError(f"prompt {s0} + budget {budget} exceeds "
                              f"max_len {self.sc.max_len}")
-        logits, cache = self._prefill(self.params, jnp.asarray(prompt)[None],
-                                      self._frontend_stub(1))
-        if not isinstance(pool, PagedSlotPool):
-            cache = grow_cache(self.cfg, cache, self.capacity)
-        first = int(jnp.argmax(logits[0, : self.cfg.vocab_size]))
-        pool.admit(slot, cache, rid=rid, pos=s0, budget=budget, first_tok=first,
-                   prompt=prompt)
+        with TraceAnnotation("engine.prefill.dispatch"):
+            logits, cache = self._prefill(self.params, jnp.asarray(prompt)[None],
+                                          self._frontend_stub(1))
+            if not isinstance(pool, PagedSlotPool):
+                cache = grow_cache(self.cfg, cache, self.capacity)
+        with TraceAnnotation("engine.prefill.readback"):
+            first = int(jnp.argmax(logits[0, : self.cfg.vocab_size]))
+        with TraceAnnotation("engine.land", rows=1):
+            pool.admit(slot, cache, rid=rid, pos=s0, budget=budget,
+                       first_tok=first, prompt=prompt)
         return first
 
     def masked_decode_step(self, pool: SlotPool) -> tuple[np.ndarray, np.ndarray]:
@@ -244,31 +261,36 @@ class InferenceEngine:
         prefilled state. Host-side slot bookkeeping (pos/emitted
         advancement, retirement) is the scheduler's job; this only advances
         the device state.
+
+        Profiler spans: ``engine.decode.prepare`` (page-table writes and the
+        host arrays), ``engine.decode.dispatch`` (uploads and the launch)
+        and ``engine.decode.readback`` (waiting for the device, the copies
+        and the guard's check).
         """
-        if isinstance(pool, PagedSlotPool):
-            # every decoding slot writes exactly position pos this tick:
-            # allocate/COW its block up-front so the write never lands in a
-            # shared or unmapped page
-            for s in pool.decoding_slots():
-                p = pool.slots[s].pos
-                pool.ensure_writable(s, p, p + 1)
-            (nxt, fin), pool.cache = self._paged_decode(
-                self.params, pool.cache, jnp.asarray(pool.tok),
-                jnp.asarray(pool.positions()), jnp.asarray(pool.decode_mask()),
-                jnp.asarray(pool.table),
-            )
+        paged = isinstance(pool, PagedSlotPool)
+        with TraceAnnotation("engine.decode.prepare"):
+            if paged:
+                # every decoding slot writes exactly position pos this tick:
+                # allocate/COW its block up-front so the write never lands
+                # in a shared or unmapped page
+                for s in pool.decoding_slots():
+                    p = pool.slots[s].pos
+                    pool.ensure_writable(s, p, p + 1)
+            host = (pool.tok, pool.positions(), pool.decode_mask())
+            if paged:
+                host += (pool.table,)
+        with TraceAnnotation("engine.decode.dispatch"):
+            step = self._paged_decode if paged else self._masked_decode
+            (nxt, fin), pool.cache = step(self.params, pool.cache,
+                                          *map(jnp.asarray, host))
+        with TraceAnnotation("engine.decode.readback"):
             nxt, fin = np.asarray(nxt), np.asarray(fin)
-            if not bool(fin[pool.decode_mask()].all()):
+            if paged and not bool(fin[pool.decode_mask()].all()):
                 # a non-finite slot may have scattered NaN into the scratch
                 # page (which every unmapped block gathers) — scrub before
                 # the next tick's gather
                 pool.scrub_scratch()
-            return nxt, fin
-        (nxt, fin), pool.cache = self._masked_decode(
-            self.params, pool.cache, jnp.asarray(pool.tok),
-            jnp.asarray(pool.positions()), jnp.asarray(pool.decode_mask()),
-        )
-        return np.asarray(nxt), np.asarray(fin)
+        return nxt, fin
 
     def _masked_decode_impl(self, params, cache, tok, pos, active):
         """vmapped per-slot decode: every slot steps at its OWN position.
@@ -285,9 +307,7 @@ class InferenceEngine:
         def one(cache_b, tok_b, pos_b):
             c1 = jax.tree.map(lambda t: jnp.expand_dims(t, 1), cache_b)
             logits, c1 = decode_step(params, c1, tok_b[None, None], pos_b, cfg)
-            v = logits[0, : cfg.vocab_size]
-            nxt = jnp.argmax(v).astype(jnp.int32)
-            fin = jnp.isfinite(v).all()
+            nxt, fin = _greedy(logits[0, : cfg.vocab_size])
             return (nxt, fin), jax.tree.map(lambda t: jnp.squeeze(t, 1), c1)
 
         return jax.vmap(one, in_axes=(1, 0, 0), out_axes=((0, 0), 1))(
@@ -334,25 +354,25 @@ class InferenceEngine:
             c1 = {**jax.tree.map(lambda t: jnp.expand_dims(t, 1), rest_b),
                   **self._paged_rows(paged, tab_b, 1)}
             logits, c1 = decode_step(params, c1, tok_b[None, None], pos_b, cfg)
-            v = logits[0, : cfg.vocab_size]
-            nxt = jnp.argmax(v).astype(jnp.int32)
-            fin = jnp.isfinite(v).all()
-            written = {}
-            for k in pkeys:
-                w = c1[k][:, 0]  # (lead, page, *tail): the one written block
-                if quant:
-                    written[k], written[f"{k}_scale"] = quantize_kv(w)
-                else:
-                    written[k] = w
-            pid = jnp.where(act_b, jnp.take(tab_b, pos_b // self.sc.page_size), 0)
+            nxt, fin = _greedy(logits[0, : cfg.vocab_size])
+            with jax.named_scope("kv_pages"):
+                written = {}
+                for k in pkeys:
+                    w = c1[k][:, 0]  # (lead, page, *tail): the one written block
+                    if quant:
+                        written[k], written[f"{k}_scale"] = quantize_kv(w)
+                    else:
+                        written[k] = w
+                pid = jnp.where(act_b, jnp.take(tab_b, pos_b // self.sc.page_size), 0)
             return (nxt, fin, written, pid), {
                 k: jnp.squeeze(c1[k], 1) for k in rest}
 
         (nxt, fin, written, pids), rest1 = jax.vmap(
             one, in_axes=(1, 0, 0, 0, 0), out_axes=((0, 0, 0, 0), 1))(
             rest, tok, pos, table, active)
-        for k in paged:
-            paged[k] = paged[k].at[:, pids].set(jnp.moveaxis(written[k], 0, 1))
+        with jax.named_scope("kv_pages"):
+            for k in paged:
+                paged[k] = paged[k].at[:, pids].set(jnp.moveaxis(written[k], 0, 1))
         return (nxt, fin), {**rest1, **paged}
 
     # -- fault injection ------------------------------------------------------
@@ -400,14 +420,16 @@ class InferenceEngine:
         if s + (budget - emitted) + 1 > self.sc.max_len:
             raise ValueError(f"resume context {s} + remaining budget "
                              f"{budget - emitted} exceeds max_len {self.sc.max_len}")
-        _, cache = self._prefill(self.params, jnp.asarray(context)[None],
-                                 self._frontend_stub(1))
-        if not isinstance(pool, PagedSlotPool):
-            cache = grow_cache(self.cfg, cache, self.capacity)
+        with TraceAnnotation("engine.prefill.dispatch"):
+            _, cache = self._prefill(self.params, jnp.asarray(context)[None],
+                                     self._frontend_stub(1))
+            if not isinstance(pool, PagedSlotPool):
+                cache = grow_cache(self.cfg, cache, self.capacity)
         # prompt=None: a resume context includes emitted tokens, which must
         # never enter the shared-prefix registry
-        pool.admit(slot, cache, rid=rid, pos=s, budget=budget,
-                   first_tok=next_tok, emitted=emitted)
+        with TraceAnnotation("engine.land", rows=1):
+            pool.admit(slot, cache, rid=rid, pos=s, budget=budget,
+                       first_tok=next_tok, emitted=emitted)
 
     # -- speculative multi-token decode --------------------------------------
     def masked_speculative_step(
@@ -434,38 +456,41 @@ class InferenceEngine:
 
         Host-side slot bookkeeping (``SlotPool.advance``, retirement, budget
         truncation) stays the scheduler's job, exactly like masked decode.
+        Profiler spans as for decode, under ``engine.verify.*``.
         """
         drafts = np.asarray(drafts, np.int32)
         k = drafts.shape[1]
         assert drafts.shape == (pool.max_batch, k) and k >= 1
-        if isinstance(pool, PagedSlotPool):
-            # no spec_slack spare rows needed: the verify window's tail
-            # blocks are allocated on demand — just check the table can hold
-            # the worst-case window (start as late as max_len-2)
-            assert (pool.max_len - 2 + k) // pool.page + 1 <= pool.max_blocks, (
-                f"verify window of {k + 1} tokens exceeds the page table "
-                f"({pool.max_blocks} blocks of {pool.page}) — raise "
-                f"spec_slack or page_size")
-            for s in pool.decoding_slots():
-                p = pool.slots[s].pos
-                pool.ensure_writable(s, p, p + k + 1)
-            (toks, acc, fin), pool.cache = self._paged_verify(
-                self.params, pool.cache, jnp.asarray(pool.tok),
-                jnp.asarray(drafts), jnp.asarray(pool.positions()),
-                jnp.asarray(pool.decode_mask()), jnp.asarray(pool.table),
-            )
+        paged = isinstance(pool, PagedSlotPool)
+        with TraceAnnotation("engine.verify.prepare"):
+            if paged:
+                # no spec_slack spare rows needed: the verify window's tail
+                # blocks are allocated on demand — just check the table can
+                # hold the worst-case window (start as late as max_len-2)
+                assert (pool.max_len - 2 + k) // pool.page + 1 <= pool.max_blocks, (
+                    f"verify window of {k + 1} tokens exceeds the page table "
+                    f"({pool.max_blocks} blocks of {pool.page}) — raise "
+                    f"spec_slack or page_size")
+                for s in pool.decoding_slots():
+                    p = pool.slots[s].pos
+                    pool.ensure_writable(s, p, p + k + 1)
+            else:
+                assert pool.slack >= k, (
+                    f"speculative verify of {k} drafts needs spec_slack >= {k} "
+                    f"spare cache rows (have {pool.slack}) — see "
+                    f"ServeConfig.spec_slack")
+            host = (pool.tok, drafts, pool.positions(), pool.decode_mask())
+            if paged:
+                host += (pool.table,)
+        with TraceAnnotation("engine.verify.dispatch"):
+            step = self._paged_verify if paged else self._masked_verify
+            (toks, acc, fin), pool.cache = step(self.params, pool.cache,
+                                                *map(jnp.asarray, host))
+        with TraceAnnotation("engine.verify.readback"):
             toks, acc, fin = np.asarray(toks), np.asarray(acc), np.asarray(fin)
-            if not bool(fin[pool.decode_mask()].all()):
+            if paged and not bool(fin[pool.decode_mask()].all()):
                 pool.scrub_scratch()
-            return toks, acc, fin
-        assert pool.slack >= k, (
-            f"speculative verify of {k} drafts needs spec_slack >= {k} "
-            f"spare cache rows (have {pool.slack}) — see ServeConfig.spec_slack")
-        (toks, acc, fin), pool.cache = self._masked_verify(
-            self.params, pool.cache, jnp.asarray(pool.tok), jnp.asarray(drafts),
-            jnp.asarray(pool.positions()), jnp.asarray(pool.decode_mask()),
-        )
-        return np.asarray(toks), np.asarray(acc), np.asarray(fin)
+        return toks, acc, fin
 
     def _masked_verify_impl(self, params, cache, tok, drafts, pos, active):
         """vmapped per-slot verify: every slot scores its own K+1 window.
@@ -481,9 +506,7 @@ class InferenceEngine:
         def one(cache_b, toks_b, pos_b):
             c1 = jax.tree.map(lambda t: jnp.expand_dims(t, 1), cache_b)
             logits, c1 = decode_verify(params, c1, toks_b[None, :], pos_b, cfg)
-            v = logits[0, :, : cfg.vocab_size]
-            g = jnp.argmax(v, axis=-1).astype(jnp.int32)
-            fin = jnp.isfinite(v).all()
+            g, fin = _greedy(logits[0, :, : cfg.vocab_size])
             # accept the longest prefix of drafts matching the greedy chain
             ok = jnp.cumprod((toks_b[1:] == g[:-1]).astype(jnp.int32))
             a = jnp.sum(ok).astype(jnp.int32)
@@ -522,9 +545,7 @@ class InferenceEngine:
             c1 = {**jax.tree.map(lambda t: jnp.expand_dims(t, 1), rest_b),
                   **self._paged_rows(paged, tab_b, nw)}
             logits, c1 = decode_verify(params, c1, toks_b[None, :], pos_b, cfg)
-            v = logits[0, :, : cfg.vocab_size]
-            g = jnp.argmax(v, axis=-1).astype(jnp.int32)
-            fin = jnp.isfinite(v).all()
+            g, fin = _greedy(logits[0, :, : cfg.vocab_size])
             ok = jnp.cumprod((toks_b[1:] == g[:-1]).astype(jnp.int32))
             a = jnp.sum(ok).astype(jnp.int32)
             c1 = commit_verify(c1, a, cfg)
@@ -548,10 +569,11 @@ class InferenceEngine:
             one, in_axes=(1, 0, 0, 0, 0), out_axes=((0, 0, 0, 0, 0), 1))(
             rest, tokens, pos, table, active)
         flat = pids.reshape(-1)  # (B * nw,) — duplicates only ever hit scratch
-        for k in paged:
-            wr = jnp.moveaxis(written[k], 1, 0)  # (lead, B, nw, page, *tail)
-            wr = wr.reshape(wr.shape[0], -1, page, *wr.shape[4:])
-            paged[k] = paged[k].at[:, flat].set(wr)
+        with jax.named_scope("kv_pages"):
+            for k in paged:
+                wr = jnp.moveaxis(written[k], 1, 0)  # (lead, B, nw, page, *tail)
+                wr = wr.reshape(wr.shape[0], -1, page, *wr.shape[4:])
+                paged[k] = paged[k].at[:, flat].set(wr)
         return (g, a, fin), {**rest1, **paged}
 
     # -- chunked prefill ------------------------------------------------------
@@ -575,38 +597,39 @@ class InferenceEngine:
                 raise ValueError(f"request {rid}: prompt {s0} + budget {budget} "
                                  f"exceeds max_len {self.sc.max_len}")
         paged = isinstance(pool, PagedSlotPool)
-        # shared-prefix hit: every member maps the common block-aligned
-        # prefix read-only and chunk-prefills only its delta. The group is
-        # formed over requests with the SAME match length, so the min is a
-        # no-op for scheduler-formed groups and a guard for direct callers.
-        shared_len, pins = 0, None
-        if paged and pool.share_prefix:
-            shared_len = min(pool.match_prefix_len(p) for p in prompts)
-            if shared_len:
-                pins = [pool.pin_prefix(p, shared_len) for p in prompts]
-        for slot, rid, budget in zip(slots, rids, budgets):
-            if not pool.admitting[slot]:  # the scheduler may have reserved already
-                pool.reserve(slot, rid=rid, s0=s0, budget=budget,
-                             shared_len=shared_len)
-        group_len = pool.virtual_len if paged else self.capacity
-        cache = init_params(
-            cache_defs(self.cfg, batch=k, max_len=group_len),
-            jax.random.PRNGKey(0),
-        )
-        if self.cfg.family == "audio":
-            ck, cv = self._cross_cache(self.params, self._frontend_stub(k))
-            cache = dict(cache, cross_k=ck.astype(cache["cross_k"].dtype),
-                         cross_v=cv.astype(cache["cross_v"].dtype))
-        if pins is not None:
-            # land the resident prefix pages in the group rows; chunking
-            # starts at shared_len (pos below) and computes only the delta
-            cache = pool.fill_group_prefix(cache, pins)
-        return ChunkedPrefillState(prompts=prompts, rids=list(rids),
-                                   budgets=list(budgets), slots=list(slots),
-                                   cache=cache,
-                                   frontend=self._chunk_frontend(k, group_len),
-                                   pos=shared_len, shared_len=shared_len,
-                                   pins=pins)
+        with TraceAnnotation("engine.begin", rows=k):
+            # shared-prefix hit: every member maps the common block-aligned
+            # prefix read-only and chunk-prefills only its delta. The group is
+            # formed over requests with the SAME match length, so the min is a
+            # no-op for scheduler-formed groups and a guard for direct callers.
+            shared_len, pins = 0, None
+            if paged and pool.share_prefix:
+                shared_len = min(pool.match_prefix_len(p) for p in prompts)
+                if shared_len:
+                    pins = [pool.pin_prefix(p, shared_len) for p in prompts]
+            for slot, rid, budget in zip(slots, rids, budgets):
+                if not pool.admitting[slot]:  # the scheduler may have reserved already
+                    pool.reserve(slot, rid=rid, s0=s0, budget=budget,
+                                 shared_len=shared_len)
+            group_len = pool.virtual_len if paged else self.capacity
+            cache = init_params(
+                cache_defs(self.cfg, batch=k, max_len=group_len),
+                jax.random.PRNGKey(0),
+            )
+            if self.cfg.family == "audio":
+                ck, cv = self._cross_cache(self.params, self._frontend_stub(k))
+                cache = dict(cache, cross_k=ck.astype(cache["cross_k"].dtype),
+                             cross_v=cv.astype(cache["cross_v"].dtype))
+            if pins is not None:
+                # land the resident prefix pages in the group rows; chunking
+                # starts at shared_len (pos below) and computes only the delta
+                cache = pool.fill_group_prefix(cache, pins)
+            return ChunkedPrefillState(prompts=prompts, rids=list(rids),
+                                       budgets=list(budgets), slots=list(slots),
+                                       cache=cache,
+                                       frontend=self._chunk_frontend(k, group_len),
+                                       pos=shared_len, shared_len=shared_len,
+                                       pins=pins)
 
     def _chunk_frontend(self, batch: int, seq_len: int | None = None):
         """VLM frontend stub padded to cache capacity on the seq axis, so
@@ -625,12 +648,7 @@ class InferenceEngine:
         capacity, dead rows are masked, not skipped). It returns only the
         logits, so no second group-sized cache is ever materialized."""
         if self._chunk_probe_fn is None:
-            cfg = self.cfg
-            self._chunk_probe_fn = jax.jit(
-                lambda p, cache, toks, pos, fe: prefill_chunk(
-                    p, cache, toks, pos, cfg, frontend_embeds=fe
-                )[0]
-            )
+            self._chunk_probe_fn = jax.jit(self._chunk_probe_impl)
         cache = init_params(
             cache_defs(self.cfg, batch=batch, max_len=self.capacity),
             jax.random.PRNGKey(0),
@@ -640,21 +658,28 @@ class InferenceEngine:
         return lambda: self._chunk_probe_fn(self.params, cache, toks,
                                             jnp.int32(0), fe)
 
+    def _chunk_probe_impl(self, params, cache, tokens, pos, frontend):
+        return prefill_chunk(params, cache, tokens, pos, self.cfg,
+                             frontend_embeds=frontend)[0]
+
     def chunked_prefill_step(self, st: "ChunkedPrefillState",
                              chunk_tokens: int) -> int:
         """Advance the admitting group by one chunk of ≤ ``chunk_tokens``
         prompt tokens. Returns the number of tokens processed; after the
         final chunk ``st.first`` holds each request's first emitted token."""
         assert not st.done
+        k = len(st.rids)
         t = min(chunk_tokens, st.s0 - st.pos)
-        toks = jnp.asarray(st.prompts[:, st.pos : st.pos + t])
-        logits, st.cache = self._chunk(self.params, st.cache, toks,
-                                       jnp.int32(st.pos), st.frontend)
+        with TraceAnnotation("engine.chunk.dispatch", rows=k):
+            toks = jnp.asarray(st.prompts[:, st.pos : st.pos + t])
+            logits, st.cache = self._chunk(self.params, st.cache, toks,
+                                           jnp.int32(st.pos), st.frontend)
         st.pos += t
         if st.done:
-            st.first = np.asarray(
-                jnp.argmax(logits[:, : self.cfg.vocab_size], axis=-1), np.int32
-            )
+            with TraceAnnotation("engine.chunk.readback", rows=k):
+                st.first = np.asarray(
+                    jnp.argmax(logits[:, : self.cfg.vocab_size], axis=-1), np.int32
+                )
         return t
 
     def finish_chunked_prefill(self, pool: SlotPool,
@@ -662,27 +687,28 @@ class InferenceEngine:
         """Land each prefilled row into its reserved slot (admitting →
         decoding) and return the group's first emitted tokens."""
         assert st.done and st.first is not None
-        if isinstance(pool, PagedSlotPool):
-            # atomic commit: check the group's TOTAL delta up front (typed
-            # PageExhausted, evicting registry pages as needed) so exhaustion
-            # never strands a half-activated group — the scheduler catches
-            # the signal and cancels the whole group cleanly
-            shared = len(st.pins[0]) if st.pins else 0
-            pool.require_pages(
-                len(st.slots) * (pool._blocks_for(st.s0) - shared))
+        with TraceAnnotation("engine.land", rows=len(st.rids)):
+            if isinstance(pool, PagedSlotPool):
+                # atomic commit: check the group's TOTAL delta up front (typed
+                # PageExhausted, evicting registry pages as needed) so exhaustion
+                # never strands a half-activated group — the scheduler catches
+                # the signal and cancels the whole group cleanly
+                shared = len(st.pins[0]) if st.pins else 0
+                pool.require_pages(
+                    len(st.slots) * (pool._blocks_for(st.s0) - shared))
+                for j, slot in enumerate(st.slots):
+                    pool.activate_from_group(
+                        slot, st.cache, j, rid=st.rids[j], pos=st.s0,
+                        budget=st.budgets[j], first_tok=int(st.first[j]),
+                        prompt=st.prompts[j],
+                        pins=st.pins[j] if st.pins else ())
+                st.pins = None  # refs transferred into the slots' tables
+                return st.first
             for j, slot in enumerate(st.slots):
-                pool.activate_from_group(
-                    slot, st.cache, j, rid=st.rids[j], pos=st.s0,
-                    budget=st.budgets[j], first_tok=int(st.first[j]),
-                    prompt=st.prompts[j],
-                    pins=st.pins[j] if st.pins else ())
-            st.pins = None  # refs transferred into the slots' tables
+                row = jax.tree.map(lambda t: t[:, j : j + 1], st.cache)
+                pool.activate(slot, row, rid=st.rids[j], pos=st.s0,
+                              budget=st.budgets[j], first_tok=int(st.first[j]))
             return st.first
-        for j, slot in enumerate(st.slots):
-            row = jax.tree.map(lambda t: t[:, j : j + 1], st.cache)
-            pool.activate(slot, row, rid=st.rids[j], pos=st.s0,
-                          budget=st.budgets[j], first_tok=int(st.first[j]))
-        return st.first
 
     def cancel_chunked_prefill(self, pool: SlotPool,
                                st: "ChunkedPrefillState") -> None:

@@ -126,13 +126,16 @@ request to the cohort's longest prompt and largest token budget).
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
+import gc
 import math
 import time
 from typing import Sequence
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.energy import DEFAULT_CHIP, TPUChip
 from repro.core.retry import RestartPolicy, StragglerDetector
@@ -647,6 +650,17 @@ class ContinuousBatchingScheduler:
         return self.pool.match_prefix_len(r.prompt)
 
     def run(self, requests: Sequence[Request]) -> ServeReport:
+        """Serve ``requests`` to completion and report the run.
+
+        Under ``jax.profiler`` the run shows as host spans on the device
+        trace's clock: one ``serve.tick`` per loop iteration, ``serve.idle``
+        around the idle policy's wait for the next event, ``serve.gc`` for
+        each Python collection pass, and the engine's own spans
+        (``engine.*``) nested in the tick that made the call."""
+        with _gc_spans():
+            return self._serve(requests)
+
+    def _serve(self, requests: Sequence[Request]) -> ServeReport:
         mode = ("speculative" if self.speculate_k
                 else "chunked" if self.prefill_chunk else "continuous")
         reqs = sorted(requests, key=lambda r: r.arrival_s)
@@ -1044,448 +1058,450 @@ class ContinuousBatchingScheduler:
                 self.detector.reset()
 
         while self.completed + shed + failed < n:
-            guard += 1
-            assert guard <= guard_max, "scheduler failed to make progress"
-            progressed = False
-            ingest()
-            shed_scan()
-
-            # quarantined/preempted requests re-admit FIRST — they hold
-            # committed work (re-admission needs the context's worst-case
-            # page budget too: s0 = prompt + already-emitted tokens,
-            # budget = the remainder). With tiers on, latency-tier entries
-            # restore ahead of batch-tier ones.
-            while pool.free_count and retry_q:
-                scan = (sorted(range(len(retry_q)),
-                               key=lambda j: tiers[retry_q[j]["rid"]] != "latency")
-                        if self.preempter is not None else range(len(retry_q)))
-                idx = next(
-                    (j for j in scan
-                     if retry_q[j]["ready_at"] <= t
-                     and not gov_defers(retry_q[j]["rid"])
-                     and pool.can_admit(
-                         len(by_rid[retry_q[j]["rid"]].prompt)
-                         + retry_q[j]["emitted"] - 1,
-                         retry_q[j]["budget"] - retry_q[j]["emitted"] + 1)),
-                    None)
-                if idx is None:
-                    break
-                e = retry_q.pop(idx)
-                try:
-                    admit_retry(e)
-                except PageExhausted:
-                    # evictable estimate went stale: wait for pages
-                    retry_q.insert(0, e)
-                    break
+            with TraceAnnotation("serve.tick"):
+                guard += 1
+                assert guard <= guard_max, "scheduler failed to make progress"
+                progressed = False
                 ingest()
+                shed_scan()
 
-            if gov is not None and paged and gov.take_preempt():
-                # brownout ladder level "preempt": shed watts by shedding
-                # batch-tier occupancy — one policy-ranked victim per
-                # escalation, consumed at a tick boundary (never mid-tick)
-                cands = victim_candidates(tier_only="batch")
-                if cands:
-                    pol = self.preempter or PreemptionPolicy()
-                    preempt_slot(pol.rank(cands)[0]["slot"])
-
-            if self.preempter is not None:
-                # SLO tiers: latency-tier arrivals go first, and a latency
-                # head that cannot admit may preempt batch-tier slots
-                # instead of queueing behind them
-                promote_latency()
-                if ready and tiers[ready[0].rid] == "latency":
-                    head = ready[0]
-                    while (not pool.can_admit(len(head.prompt),
-                                              head.new_tokens,
-                                              shared_len=self._prefix_len(head))):
-                        cands = victim_candidates(tier_only="batch")
-                        if not cands:
-                            break
-                        preempt_slot(self.preempter.rank(cands)[0]["slot"])
-
-            if (self.prefill_chunk is None or chunk_disabled
-                    or (gov is not None and not gov.chunk_ok())):
-                # BLOCKING admissions: fill free slots from the ready queue;
-                # each prefill stalls the whole pool. can_admit covers the
-                # free-slot check and (paged) the head's worst-case page
-                # budget — admission stays FIFO, so a page-starved head
-                # waits rather than being jumped
-                while (ready and not gov_defers(ready[0].rid)
-                       and pool.can_admit(len(ready[0].prompt),
-                                          ready[0].new_tokens)):
-                    r = ready.popleft()
-                    rec = recs[r.rid]
-                    # t advanced during earlier admissions — re-check
-                    if self._infeasible(t, len(r.prompt), r.new_tokens - 1,
-                                        r.arrival_s, deadlines[r.rid]):
-                        rec.shed = True
-                        shed += 1
-                        continue
-                    slot = pool.next_free()
-                    tp = self.cal.prefill_s(1, len(r.prompt))
-                    if self.execute:
-                        try:
-                            first = self.engine.prefill_into_slot(
-                                pool, slot, r.prompt, rid=r.rid,
-                                budget=r.new_tokens)
-                        except PageExhausted:
-                            # can_admit's evictable estimate went stale mid-
-                            # scan; the pool unwound cleanly — wait for pages
-                            ready.appendleft(r)
-                            break
-                    else:
-                        first = 0
-                        pool.admit_virtual(slot, rid=r.rid, pos=len(r.prompt),
-                                           budget=r.new_tokens)
-                    pool.slots[slot].tier = tiers[r.rid]
-                    rec.admit_s = t
-                    _, ej = busy_tick("prefill", tp, self.prefill_util)
-                    rec.energy_j += ej
-                    rec.tokens.append(first)
-                    if self.drafter is not None:
-                        self.drafter.begin(r.rid, list(r.prompt) + [first])
-                    if self.throttle is not None:
-                        self.throttle.begin(r.rid)
-                    self.admitted += 1
-                    self._maybe_finish(slot, rec, t, deadlines[r.rid])
+                # quarantined/preempted requests re-admit FIRST — they hold
+                # committed work (re-admission needs the context's worst-case
+                # page budget too: s0 = prompt + already-emitted tokens,
+                # budget = the remainder). With tiers on, latency-tier entries
+                # restore ahead of batch-tier ones.
+                while pool.free_count and retry_q:
+                    scan = (sorted(range(len(retry_q)),
+                                   key=lambda j: tiers[retry_q[j]["rid"]] != "latency")
+                            if self.preempter is not None else range(len(retry_q)))
+                    idx = next(
+                        (j for j in scan
+                         if retry_q[j]["ready_at"] <= t
+                         and not gov_defers(retry_q[j]["rid"])
+                         and pool.can_admit(
+                             len(by_rid[retry_q[j]["rid"]].prompt)
+                             + retry_q[j]["emitted"] - 1,
+                             retry_q[j]["budget"] - retry_q[j]["emitted"] + 1)),
+                        None)
+                    if idx is None:
+                        break
+                    e = retry_q.pop(idx)
+                    try:
+                        admit_retry(e)
+                    except PageExhausted:
+                        # evictable estimate went stale: wait for pages
+                        retry_q.insert(0, e)
+                        break
                     ingest()
-            elif group is None and ready and pool.free_count:
-                # CHUNKED admission: reserve slots for the maximal FIFO run
-                # of waiting same-prompt-length (and, under paged prefix
-                # sharing, same shared-prefix-length) requests — one batched
-                # prefill. Each member reserves AS it joins, so the paged
-                # pool's page-budget accounting sees the cumulative claim
-                # and can_admit stops the run before pages oversubscribe.
-                m0 = self._prefix_len(ready[0])
-                g: list[Request] = []
-                slots: list[int] = []
-                while (ready and pool.free_count
-                       and not gov_defers(ready[0].rid)
-                       and (not g
-                            or (len(ready[0].prompt) == len(g[0].prompt)
-                                and self._prefix_len(ready[0]) == m0))
-                       and pool.can_admit(len(ready[0].prompt),
-                                          ready[0].new_tokens,
-                                          shared_len=m0)):
-                    r = ready.popleft()
-                    slot = pool.next_free()
-                    pool.reserve(slot, rid=r.rid, s0=len(r.prompt),
-                                 budget=r.new_tokens, shared_len=m0)
-                    pool.slots[slot].tier = tiers[r.rid]
-                    g.append(r)
-                    slots.append(slot)
-                    recs[r.rid].admit_s = t
-                    self.admitted += 1
-                if g:
-                    prompts = np.stack([r.prompt for r in g]).astype(np.int32)
-                    rids = [r.rid for r in g]
-                    budgets = [r.new_tokens for r in g]
-                    group_fails = 0
-                    group_spent_ok = 0.0
-                    if self.execute:
-                        group = self.engine.begin_chunked_prefill(
-                            pool, slots, prompts, rids=rids, budgets=budgets)
-                    else:
-                        group = ChunkedPrefillState(prompts=prompts, rids=rids,
-                                                    budgets=budgets, slots=slots)
 
-            if group is not None:
-                # PREFILL: advance the admitting group by one chunk; the
-                # chunk's energy is split over the group's requests
-                k = len(group.rids)
-                ttok = min(self.prefill_chunk, group.s0 - group.pos)
-                fail = inj.chunk_fails() if inj is not None else False
-                stall = inj.stall() if inj is not None else 1.0
-                therm = inj.thermal() if inj is not None else None
-                if therm is not None:
-                    env.throttle(t, therm,
-                                 self.faults.therm_ticks * self.cal.step_s())
-                tp, te = busy_tick("prefill", self.cal.chunk_s(k, ttok),
-                                   self.prefill_util, stall)
-                self.chunks += 1
-                observe_tick(tp)
-                share = te / k
-                for rid in group.rids:
-                    recs[rid].energy_j += share
-                progressed = True
-                if fail:
-                    # the tick's work is lost: the group cache did not advance
-                    chunk_faults += 1
-                    group_fails += 1
-                    for rid in group.rids:
-                        recs[rid].waste_j += share
-                    if group_fails > self.retry.max_restarts:
-                        # past the retry budget: DEGRADE — drop the group's
-                        # reservations, requeue its members for blocking
-                        # admission, and keep chunking off for this run
-                        degraded += 1
-                        chunk_disabled = True
-                        for rid in group.rids:
-                            recs[rid].waste_j += group_spent_ok / k
-                        if self.execute:
-                            # also releases any pinned shared-prefix pages
-                            self.engine.cancel_chunked_prefill(pool, group)
-                        else:
-                            for slot in group.slots:
-                                pool.retire(slot)
-                        self.admitted -= k  # they re-admit through blocking
-                        for r in reversed([by_rid[rid] for rid in group.rids]):
-                            ready.appendleft(r)
-                        group = None
-                else:
-                    group_fails = 0
-                    group_spent_ok += share * k
-                    if self.execute:
-                        self.engine.chunked_prefill_step(group, self.prefill_chunk)
-                    else:
-                        group.pos += ttok
-                    if group.done:
+                if gov is not None and paged and gov.take_preempt():
+                    # brownout ladder level "preempt": shed watts by shedding
+                    # batch-tier occupancy — one policy-ranked victim per
+                    # escalation, consumed at a tick boundary (never mid-tick)
+                    cands = victim_candidates(tier_only="batch")
+                    if cands:
+                        pol = self.preempter or PreemptionPolicy()
+                        preempt_slot(pol.rank(cands)[0]["slot"])
+
+                if self.preempter is not None:
+                    # SLO tiers: latency-tier arrivals go first, and a latency
+                    # head that cannot admit may preempt batch-tier slots
+                    # instead of queueing behind them
+                    promote_latency()
+                    if ready and tiers[ready[0].rid] == "latency":
+                        head = ready[0]
+                        while (not pool.can_admit(len(head.prompt),
+                                                  head.new_tokens,
+                                                  shared_len=self._prefix_len(head))):
+                            cands = victim_candidates(tier_only="batch")
+                            if not cands:
+                                break
+                            preempt_slot(self.preempter.rank(cands)[0]["slot"])
+
+                if (self.prefill_chunk is None or chunk_disabled
+                        or (gov is not None and not gov.chunk_ok())):
+                    # BLOCKING admissions: fill free slots from the ready queue;
+                    # each prefill stalls the whole pool. can_admit covers the
+                    # free-slot check and (paged) the head's worst-case page
+                    # budget — admission stays FIFO, so a page-starved head
+                    # waits rather than being jumped
+                    while (ready and not gov_defers(ready[0].rid)
+                           and pool.can_admit(len(ready[0].prompt),
+                                              ready[0].new_tokens)):
+                        r = ready.popleft()
+                        rec = recs[r.rid]
+                        # t advanced during earlier admissions — re-check
+                        if self._infeasible(t, len(r.prompt), r.new_tokens - 1,
+                                            r.arrival_s, deadlines[r.rid]):
+                            rec.shed = True
+                            shed += 1
+                            continue
+                        slot = pool.next_free()
+                        tp = self.cal.prefill_s(1, len(r.prompt))
                         if self.execute:
                             try:
-                                first = self.engine.finish_chunked_prefill(
-                                    pool, group)
+                                first = self.engine.prefill_into_slot(
+                                    pool, slot, r.prompt, rid=r.rid,
+                                    budget=r.new_tokens)
                             except PageExhausted:
-                                # the group's delta blocks cannot land (the
-                                # atomic pre-check caught it before touching
-                                # any slot): DEGRADE to blocking admission,
-                                # exactly like a chunk-fault budget blowout
-                                degraded += 1
-                                chunk_disabled = True
-                                for rid in group.rids:
-                                    recs[rid].waste_j += group_spent_ok / k
-                                self.engine.cancel_chunked_prefill(pool, group)
-                                self.admitted -= k
-                                for r in reversed(
-                                        [by_rid[rid] for rid in group.rids]):
-                                    ready.appendleft(r)
-                                group = None
-                                continue
+                                # can_admit's evictable estimate went stale mid-
+                                # scan; the pool unwound cleanly — wait for pages
+                                ready.appendleft(r)
+                                break
                         else:
-                            first = np.zeros(k, np.int32)
-                            for j, slot in enumerate(group.slots):
-                                pool.activate(slot, None, rid=group.rids[j],
-                                              pos=group.s0,
-                                              budget=group.budgets[j],
-                                              first_tok=0)
-                        for j, rid in enumerate(group.rids):
-                            rec = recs[rid]
-                            pool.slots[group.slots[j]].tier = tiers[rid]
-                            rec.tokens.append(int(first[j]))
-                            if self.drafter is not None:
-                                self.drafter.begin(
-                                    rid, list(group.prompts[j]) + [int(first[j])])
-                            if self.throttle is not None:
-                                self.throttle.begin(rid)
-                            self._maybe_finish(group.slots[j], rec, t,
-                                               deadlines[rid])
-                        group = None
-
-            # sample occupancy at its per-tick high-water mark (admissions
-            # done, nothing retired yet this tick)
-            peak_active = max(peak_active, pool.active_count)
-
-            decoding = pool.decoding_slots()
-            spec_k = 0
-            win: dict[int, int] | None = None
-            if decoding and self.speculate_k:
-                # the brownout ladder caps windows from above (halved at
-                # spec_half, 0 at spec_off and beyond) — BATCH-tier slots
-                # only: latency-tier work is the last thing the ladder
-                # touches, so its windows ride through undegraded
-                k_gov = (gov.spec_cap(self.speculate_k) if gov is not None
-                         else self.speculate_k)
-                if gov is not None or self.throttle is not None:
-                    # per-slot windows; the pool's verify width is their max
-                    # (windows move in powers of two, so the K-keyed verify
-                    # jit sees at most log2(K) distinct signatures)
-                    win = {}
-                    for s in decoding:
-                        rid = pool.slots[s].rid
-                        k = (self.speculate_k if tiers[rid] == "latency"
-                             else k_gov)
+                            first = 0
+                            pool.admit_virtual(slot, rid=r.rid, pos=len(r.prompt),
+                                               budget=r.new_tokens)
+                        pool.slots[slot].tier = tiers[r.rid]
+                        rec.admit_s = t
+                        _, ej = busy_tick("prefill", tp, self.prefill_util)
+                        rec.energy_j += ej
+                        rec.tokens.append(first)
+                        if self.drafter is not None:
+                            self.drafter.begin(r.rid, list(r.prompt) + [first])
                         if self.throttle is not None:
-                            k = min(self.throttle.window(rid), k)
-                        win[s] = k
-                    spec_k = max(win.values())
-                    if spec_k == 0 and self.throttle is not None:
-                        throttled += 1  # whole pool stalled: plain tick
-                else:
-                    spec_k = k_gov
+                            self.throttle.begin(r.rid)
+                        self.admitted += 1
+                        self._maybe_finish(slot, rec, t, deadlines[r.rid])
+                        ingest()
+                elif group is None and ready and pool.free_count:
+                    # CHUNKED admission: reserve slots for the maximal FIFO run
+                    # of waiting same-prompt-length (and, under paged prefix
+                    # sharing, same shared-prefix-length) requests — one batched
+                    # prefill. Each member reserves AS it joins, so the paged
+                    # pool's page-budget accounting sees the cumulative claim
+                    # and can_admit stops the run before pages oversubscribe.
+                    m0 = self._prefix_len(ready[0])
+                    g: list[Request] = []
+                    slots: list[int] = []
+                    while (ready and pool.free_count
+                           and not gov_defers(ready[0].rid)
+                           and (not g
+                                or (len(ready[0].prompt) == len(g[0].prompt)
+                                    and self._prefix_len(ready[0]) == m0))
+                           and pool.can_admit(len(ready[0].prompt),
+                                              ready[0].new_tokens,
+                                              shared_len=m0)):
+                        r = ready.popleft()
+                        slot = pool.next_free()
+                        pool.reserve(slot, rid=r.rid, s0=len(r.prompt),
+                                     budget=r.new_tokens, shared_len=m0)
+                        pool.slots[slot].tier = tiers[r.rid]
+                        g.append(r)
+                        slots.append(slot)
+                        recs[r.rid].admit_s = t
+                        self.admitted += 1
+                    if g:
+                        prompts = np.stack([r.prompt for r in g]).astype(np.int32)
+                        rids = [r.rid for r in g]
+                        budgets = [r.new_tokens for r in g]
+                        group_fails = 0
+                        group_spent_ok = 0.0
+                        if self.execute:
+                            group = self.engine.begin_chunked_prefill(
+                                pool, slots, prompts, rids=rids, budgets=budgets)
+                        else:
+                            group = ChunkedPrefillState(prompts=prompts, rids=rids,
+                                                        budgets=budgets, slots=slots)
 
-            if paged and decoding:
-                # MEMORY PRESSURE phase: the page-pressure fault may pin
-                # free pages out for this tick, then the watermark preempts
-                # victims until the tick's worst-case growth fits
-                if inj is not None:
-                    stolen = inj.press()
-                    if stolen:
-                        press_pins = pool.pin_free_pages(stolen)
-                if force_plain:
-                    spec_k = 0  # one-shot: retry the failed tick unspeculated
-                if self.preempter is not None:
-                    relieve_pressure(spec_k + 1)
-                    decoding = pool.decoding_slots()
-            force_plain = False
+                if group is not None:
+                    # PREFILL: advance the admitting group by one chunk; the
+                    # chunk's energy is split over the group's requests
+                    k = len(group.rids)
+                    ttok = min(self.prefill_chunk, group.s0 - group.pos)
+                    fail = inj.chunk_fails() if inj is not None else False
+                    stall = inj.stall() if inj is not None else 1.0
+                    therm = inj.thermal() if inj is not None else None
+                    if therm is not None:
+                        env.throttle(t, therm,
+                                     self.faults.therm_ticks * self.cal.step_s())
+                    tp, te = busy_tick("prefill", self.cal.chunk_s(k, ttok),
+                                       self.prefill_util, stall)
+                    self.chunks += 1
+                    observe_tick(tp)
+                    share = te / k
+                    for rid in group.rids:
+                        recs[rid].energy_j += share
+                    progressed = True
+                    if fail:
+                        # the tick's work is lost: the group cache did not advance
+                        chunk_faults += 1
+                        group_fails += 1
+                        for rid in group.rids:
+                            recs[rid].waste_j += share
+                        if group_fails > self.retry.max_restarts:
+                            # past the retry budget: DEGRADE — drop the group's
+                            # reservations, requeue its members for blocking
+                            # admission, and keep chunking off for this run
+                            degraded += 1
+                            chunk_disabled = True
+                            for rid in group.rids:
+                                recs[rid].waste_j += group_spent_ok / k
+                            if self.execute:
+                                # also releases any pinned shared-prefix pages
+                                self.engine.cancel_chunked_prefill(pool, group)
+                            else:
+                                for slot in group.slots:
+                                    pool.retire(slot)
+                            self.admitted -= k  # they re-admit through blocking
+                            for r in reversed([by_rid[rid] for rid in group.rids]):
+                                ready.appendleft(r)
+                            group = None
+                    else:
+                        group_fails = 0
+                        group_spent_ok += share * k
+                        if self.execute:
+                            self.engine.chunked_prefill_step(group, self.prefill_chunk)
+                        else:
+                            group.pos += ttok
+                        if group.done:
+                            if self.execute:
+                                try:
+                                    first = self.engine.finish_chunked_prefill(
+                                        pool, group)
+                                except PageExhausted:
+                                    # the group's delta blocks cannot land (the
+                                    # atomic pre-check caught it before touching
+                                    # any slot): DEGRADE to blocking admission,
+                                    # exactly like a chunk-fault budget blowout
+                                    degraded += 1
+                                    chunk_disabled = True
+                                    for rid in group.rids:
+                                        recs[rid].waste_j += group_spent_ok / k
+                                    self.engine.cancel_chunked_prefill(pool, group)
+                                    self.admitted -= k
+                                    for r in reversed(
+                                            [by_rid[rid] for rid in group.rids]):
+                                        ready.appendleft(r)
+                                    group = None
+                                    continue
+                            else:
+                                first = np.zeros(k, np.int32)
+                                for j, slot in enumerate(group.slots):
+                                    pool.activate(slot, None, rid=group.rids[j],
+                                                  pos=group.s0,
+                                                  budget=group.budgets[j],
+                                                  first_tok=0)
+                            for j, rid in enumerate(group.rids):
+                                rec = recs[rid]
+                                pool.slots[group.slots[j]].tier = tiers[rid]
+                                rec.tokens.append(int(first[j]))
+                                if self.drafter is not None:
+                                    self.drafter.begin(
+                                        rid, list(group.prompts[j]) + [int(first[j])])
+                                if self.throttle is not None:
+                                    self.throttle.begin(rid)
+                                self._maybe_finish(group.slots[j], rec, t,
+                                                   deadlines[rid])
+                            group = None
 
-            if spec_k and decoding:
-                # SPECULATIVE DECODING: draft K candidates per decoding slot
-                # (admitting slots stay out of the verify mask), score every
-                # slot's K+1 window in ONE verify pass, commit the accepted
-                # prefixes. The tick is charged like a decode step plus the
-                # per-candidate increment, amortized by tokens committed.
-                victims = inj.poison_victims(decoding) if inj is not None else []
-                stall = inj.stall() if inj is not None else 1.0
-                therm = inj.thermal() if inj is not None else None
-                if therm is not None:
-                    env.throttle(t, therm,
-                                 self.faults.therm_ticks * self.cal.step_s())
-                if victims and self.execute:
-                    for s in victims:
-                        self.engine.poison_slot(pool, s)
-                drafts = np.zeros((pool.max_batch, spec_k), np.int32)
-                for slot in decoding:
-                    drafts[slot] = self.drafter.propose(
-                        pool.slots[slot].rid)[:spec_k]
-                if self.execute:
-                    try:
-                        toks, acc, fin = self.engine.masked_speculative_step(
-                            pool, drafts)
-                    except PageExhausted:
-                        # verify tail blocks outran the pool mid-tick (the
-                        # crash-era RuntimeError path): preempt one victim,
-                        # retry the tick as plain decode (within-reservation
-                        # demand, always satisfiable after the preempt)
-                        if not emergency_preempt():
-                            tq = [s for s in pool.decoding_slots()
-                                  if s in pool._slot_tainted]
-                            if tq:
-                                quarantine(tq[0])
-                        force_plain = True
-                        release_press()
-                        continue
-                else:  # the virtual model's greedy chain is all zeros
-                    toks = np.zeros((pool.max_batch, spec_k + 1), np.int32)
-                    acc = np.cumprod(drafts == 0, axis=1).sum(axis=1)
-                    fin = np.ones(pool.max_batch, bool)
-                    fin[victims] = False
-                util = len(decoding) / pool.max_batch
-                ts, tick_e = busy_tick("verify", self.cal.verify_s(spec_k),
-                                       util, stall)
-                self.verify_ticks += 1
-                observe_tick(ts)
-                # a slot never overshoots its budget (acceptance past the
-                # remaining budget is truncated, the slot retires mid-verify)
-                # nor its own throttle window; a quarantined slot's discarded
-                # work weighs like one token in the amortization
-                caps = {s: (win[s] if win is not None else spec_k)
-                        for s in decoding}
-                emit = {s: (1 if not fin[s] else
-                            min(int(acc[s]) + 1, caps[s] + 1,
-                                pool.slots[s].budget - pool.slots[s].emitted))
-                        for s in decoding}
-                total = sum(emit.values())
-                for slot in decoding:
-                    info = pool.slots[slot]
-                    rec = recs[info.rid]
-                    share = tick_e * emit[slot] / total
-                    rec.energy_j += share
-                    if not fin[slot]:
-                        rec.waste_j += share
-                        quarantine(slot)
-                        continue
-                    n_tok = emit[slot]
-                    out = toks[slot, :n_tok].tolist()
-                    pool.advance(slot, n_tok, int(toks[slot, n_tok - 1]))
-                    self.drafter.observe(info.rid, out)
-                    if self.throttle is not None:
-                        self.throttle.observe(
-                            info.rid, min(int(acc[slot]), caps[slot]), caps[slot])
-                    rec.tokens.extend(out)
-                    self.accepted_tokens += n_tok
-                    self._maybe_finish(slot, rec, t, deadlines[info.rid])
-                progressed = True
-            elif decoding:
-                # DECODING: one masked step over the pool at measured occupancy
-                victims = inj.poison_victims(decoding) if inj is not None else []
-                stall = inj.stall() if inj is not None else 1.0
-                therm = inj.thermal() if inj is not None else None
-                if therm is not None:
-                    env.throttle(t, therm,
-                                 self.faults.therm_ticks * self.cal.step_s())
-                if victims and self.execute:
-                    for s in victims:
-                        self.engine.poison_slot(pool, s)
-                util = len(decoding) / pool.max_batch
-                if self.execute:
-                    try:
-                        nxt, fin = self.engine.masked_decode_step(pool)
-                    except PageExhausted:
-                        if not emergency_preempt():
-                            tq = [s for s in pool.decoding_slots()
-                                  if s in pool._slot_tainted]
-                            if tq:
-                                quarantine(tq[0])
-                        release_press()
-                        continue
-                else:
-                    nxt = np.zeros(pool.max_batch, np.int32)
-                    fin = np.ones(pool.max_batch, bool)
-                    fin[victims] = False
-                ts, te = busy_tick("decode", self.cal.step_s(), util, stall)
-                observe_tick(ts)
-                share = te / len(decoding)
-                for slot in decoding:
-                    info = pool.slots[slot]
-                    rec = recs[info.rid]
-                    rec.energy_j += share
-                    if not fin[slot]:
-                        rec.waste_j += share
-                        quarantine(slot)
-                        continue
-                    tok = int(nxt[slot])
-                    pool.advance(slot, 1, tok)
-                    rec.tokens.append(tok)
-                    if self.speculate_k and self.drafter is not None:
-                        # throttled-to-0 tick: keep the drafter's history in
-                        # sync so a re-opened window drafts from truth
-                        self.drafter.observe(info.rid, [tok])
-                    self._maybe_finish(slot, rec, t, deadlines[info.rid])
-                progressed = True
+                # sample occupancy at its per-tick high-water mark (admissions
+                # done, nothing retired yet this tick)
+                peak_active = max(peak_active, pool.active_count)
 
-            release_press()
+                decoding = pool.decoding_slots()
+                spec_k = 0
+                win: dict[int, int] | None = None
+                if decoding and self.speculate_k:
+                    # the brownout ladder caps windows from above (halved at
+                    # spec_half, 0 at spec_off and beyond) — BATCH-tier slots
+                    # only: latency-tier work is the last thing the ladder
+                    # touches, so its windows ride through undegraded
+                    k_gov = (gov.spec_cap(self.speculate_k) if gov is not None
+                             else self.speculate_k)
+                    if gov is not None or self.throttle is not None:
+                        # per-slot windows; the pool's verify width is their max
+                        # (windows move in powers of two, so the K-keyed verify
+                        # jit sees at most log2(K) distinct signatures)
+                        win = {}
+                        for s in decoding:
+                            rid = pool.slots[s].rid
+                            k = (self.speculate_k if tiers[rid] == "latency"
+                                 else k_gov)
+                            if self.throttle is not None:
+                                k = min(self.throttle.window(rid), k)
+                            win[s] = k
+                        spec_k = max(win.values())
+                        if spec_k == 0 and self.throttle is not None:
+                            throttled += 1  # whole pool stalled: plain tick
+                    else:
+                        spec_k = k_gov
 
-            if not progressed and group is None and (i < n or retry_q):
-                # IDLE/OFF: pool drained — the online policy owns the gap up
-                # to the next event (an arrival, or a retry backoff expiry).
-                # (everything admissible by t was admitted above, so the gap
-                # is strictly positive)
-                pending = []
-                if i < n:
-                    pending.append(reqs[i].arrival_s)
-                if retry_q:
-                    pending.append(min(e["ready_at"] for e in retry_q))
-                target = min(pending)
-                gap = target - t
-                assert gap > 0
-                out = self.policy.on_gap(gap)
-                gap_energy += out.energy_j
-                reloads += int(out.slept)
-                gap_t0 = t
-                t = target + out.wake_s
-                record_span(gap_t0, t, out.energy_j)
-                if gov is not None:
-                    # quiet spells de-escalate the ladder
-                    gap_cap = env.cap_w(t) if env is not None else math.inf
-                    if bud_ledger is not None:
-                        gap_cap = min(gap_cap, bud_ledger.cap_w)
-                    gov.update(t, gap_cap)
+                if paged and decoding:
+                    # MEMORY PRESSURE phase: the page-pressure fault may pin
+                    # free pages out for this tick, then the watermark preempts
+                    # victims until the tick's worst-case growth fits
+                    if inj is not None:
+                        stolen = inj.press()
+                        if stolen:
+                            press_pins = pool.pin_free_pages(stolen)
+                    if force_plain:
+                        spec_k = 0  # one-shot: retry the failed tick unspeculated
+                    if self.preempter is not None:
+                        relieve_pressure(spec_k + 1)
+                        decoding = pool.decoding_slots()
+                force_plain = False
 
-            peak_active = max(peak_active, pool.active_count)
+                if spec_k and decoding:
+                    # SPECULATIVE DECODING: draft K candidates per decoding slot
+                    # (admitting slots stay out of the verify mask), score every
+                    # slot's K+1 window in ONE verify pass, commit the accepted
+                    # prefixes. The tick is charged like a decode step plus the
+                    # per-candidate increment, amortized by tokens committed.
+                    victims = inj.poison_victims(decoding) if inj is not None else []
+                    stall = inj.stall() if inj is not None else 1.0
+                    therm = inj.thermal() if inj is not None else None
+                    if therm is not None:
+                        env.throttle(t, therm,
+                                     self.faults.therm_ticks * self.cal.step_s())
+                    if victims and self.execute:
+                        for s in victims:
+                            self.engine.poison_slot(pool, s)
+                    drafts = np.zeros((pool.max_batch, spec_k), np.int32)
+                    for slot in decoding:
+                        drafts[slot] = self.drafter.propose(
+                            pool.slots[slot].rid)[:spec_k]
+                    if self.execute:
+                        try:
+                            toks, acc, fin = self.engine.masked_speculative_step(
+                                pool, drafts)
+                        except PageExhausted:
+                            # verify tail blocks outran the pool mid-tick (the
+                            # crash-era RuntimeError path): preempt one victim,
+                            # retry the tick as plain decode (within-reservation
+                            # demand, always satisfiable after the preempt)
+                            if not emergency_preempt():
+                                tq = [s for s in pool.decoding_slots()
+                                      if s in pool._slot_tainted]
+                                if tq:
+                                    quarantine(tq[0])
+                            force_plain = True
+                            release_press()
+                            continue
+                    else:  # the virtual model's greedy chain is all zeros
+                        toks = np.zeros((pool.max_batch, spec_k + 1), np.int32)
+                        acc = np.cumprod(drafts == 0, axis=1).sum(axis=1)
+                        fin = np.ones(pool.max_batch, bool)
+                        fin[victims] = False
+                    util = len(decoding) / pool.max_batch
+                    ts, tick_e = busy_tick("verify", self.cal.verify_s(spec_k),
+                                           util, stall)
+                    self.verify_ticks += 1
+                    observe_tick(ts)
+                    # a slot never overshoots its budget (acceptance past the
+                    # remaining budget is truncated, the slot retires mid-verify)
+                    # nor its own throttle window; a quarantined slot's discarded
+                    # work weighs like one token in the amortization
+                    caps = {s: (win[s] if win is not None else spec_k)
+                            for s in decoding}
+                    emit = {s: (1 if not fin[s] else
+                                min(int(acc[s]) + 1, caps[s] + 1,
+                                    pool.slots[s].budget - pool.slots[s].emitted))
+                            for s in decoding}
+                    total = sum(emit.values())
+                    for slot in decoding:
+                        info = pool.slots[slot]
+                        rec = recs[info.rid]
+                        share = tick_e * emit[slot] / total
+                        rec.energy_j += share
+                        if not fin[slot]:
+                            rec.waste_j += share
+                            quarantine(slot)
+                            continue
+                        n_tok = emit[slot]
+                        out = toks[slot, :n_tok].tolist()
+                        pool.advance(slot, n_tok, int(toks[slot, n_tok - 1]))
+                        self.drafter.observe(info.rid, out)
+                        if self.throttle is not None:
+                            self.throttle.observe(
+                                info.rid, min(int(acc[slot]), caps[slot]), caps[slot])
+                        rec.tokens.extend(out)
+                        self.accepted_tokens += n_tok
+                        self._maybe_finish(slot, rec, t, deadlines[info.rid])
+                    progressed = True
+                elif decoding:
+                    # DECODING: one masked step over the pool at measured occupancy
+                    victims = inj.poison_victims(decoding) if inj is not None else []
+                    stall = inj.stall() if inj is not None else 1.0
+                    therm = inj.thermal() if inj is not None else None
+                    if therm is not None:
+                        env.throttle(t, therm,
+                                     self.faults.therm_ticks * self.cal.step_s())
+                    if victims and self.execute:
+                        for s in victims:
+                            self.engine.poison_slot(pool, s)
+                    util = len(decoding) / pool.max_batch
+                    if self.execute:
+                        try:
+                            nxt, fin = self.engine.masked_decode_step(pool)
+                        except PageExhausted:
+                            if not emergency_preempt():
+                                tq = [s for s in pool.decoding_slots()
+                                      if s in pool._slot_tainted]
+                                if tq:
+                                    quarantine(tq[0])
+                            release_press()
+                            continue
+                    else:
+                        nxt = np.zeros(pool.max_batch, np.int32)
+                        fin = np.ones(pool.max_batch, bool)
+                        fin[victims] = False
+                    ts, te = busy_tick("decode", self.cal.step_s(), util, stall)
+                    observe_tick(ts)
+                    share = te / len(decoding)
+                    for slot in decoding:
+                        info = pool.slots[slot]
+                        rec = recs[info.rid]
+                        rec.energy_j += share
+                        if not fin[slot]:
+                            rec.waste_j += share
+                            quarantine(slot)
+                            continue
+                        tok = int(nxt[slot])
+                        pool.advance(slot, 1, tok)
+                        rec.tokens.append(tok)
+                        if self.speculate_k and self.drafter is not None:
+                            # throttled-to-0 tick: keep the drafter's history in
+                            # sync so a re-opened window drafts from truth
+                            self.drafter.observe(info.rid, [tok])
+                        self._maybe_finish(slot, rec, t, deadlines[info.rid])
+                    progressed = True
 
-            # conservation: every request is in exactly one place
-            assert (self.completed + shed + failed + pool.active_count
-                    + len(retry_q) + len(ready) + (n - i) == n), \
-                "request leak: terminal + in-flight + queued != total"
+                release_press()
+
+                if not progressed and group is None and (i < n or retry_q):
+                    # IDLE/OFF: pool drained — the online policy owns the gap up
+                    # to the next event (an arrival, or a retry backoff expiry).
+                    # (everything admissible by t was admitted above, so the gap
+                    # is strictly positive)
+                    pending = []
+                    if i < n:
+                        pending.append(reqs[i].arrival_s)
+                    if retry_q:
+                        pending.append(min(e["ready_at"] for e in retry_q))
+                    target = min(pending)
+                    gap = target - t
+                    assert gap > 0
+                    with TraceAnnotation("serve.idle"):
+                        out = self.policy.on_gap(gap)
+                    gap_energy += out.energy_j
+                    reloads += int(out.slept)
+                    gap_t0 = t
+                    t = target + out.wake_s
+                    record_span(gap_t0, t, out.energy_j)
+                    if gov is not None:
+                        # quiet spells de-escalate the ladder
+                        gap_cap = env.cap_w(t) if env is not None else math.inf
+                        if bud_ledger is not None:
+                            gap_cap = min(gap_cap, bud_ledger.cap_w)
+                        gov.update(t, gap_cap)
+
+                peak_active = max(peak_active, pool.active_count)
+
+                # conservation: every request is in exactly one place
+                assert (self.completed + shed + failed + pool.active_count
+                        + len(retry_q) + len(ready) + (n - i) == n), \
+                    "request leak: terminal + in-flight + queued != total"
 
         records = [recs[r.rid] for r in reqs]
         energy = (self.profile.e_cfg_j  # the one true initial configuration
@@ -1527,6 +1543,28 @@ class ContinuousBatchingScheduler:
                            peak_budget_window_j=(
                                bud_ledger.peak_window_j
                                if bud_ledger is not None else 0.0))
+
+
+@contextlib.contextmanager
+def _gc_spans():
+    """Mark each Python collection pass as a ``serve.gc`` span while the
+    block runs: a long pass stalls the host between two device steps, and
+    in a profile it shows as an idle gap with this span over it."""
+    open_spans = []
+
+    def on_gc(phase, info):
+        if phase == "start":
+            span = TraceAnnotation("serve.gc")
+            span.__enter__()
+            open_spans.append(span)
+        elif open_spans:
+            open_spans.pop().__exit__(None, None, None)
+
+    gc.callbacks.append(on_gc)
+    try:
+        yield
+    finally:
+        gc.callbacks.remove(on_gc)
 
 
 # ---------------------------------------------------------------------------
