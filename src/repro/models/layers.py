@@ -150,35 +150,51 @@ def attention_chunked(q, k, v, *, causal: bool, chunk: int = 1024) -> jax.Array:
     return out.swapaxes(1, 2).astype(q.dtype)  # (B,Sq,H,D)
 
 
+def _grouped_attention(q, k_cache, v_cache, qpos) -> jax.Array:
+    """q: (B,T,H,D) queries at positions ``qpos`` (T,); caches (B,Smax,KV,D).
+
+    Query head ``j*G + i`` reads KV head ``j`` (G = H // KV, from the
+    shapes), the layout ``_repeat_kv`` gives, but q is viewed as
+    (B,T,KV,G,D) and each KV head is contracted with its group in place:
+    K and V are read once in their own dtype, with no copy repeated to H
+    heads and no f32 copy. Scores accumulate in f32 (bf16 products are
+    exact there), the softmax is f32, and P stays f32 against V. The
+    sequence-sharding pins are ``attention_decode``'s.
+    """
+    b, t, h, d = q.shape
+    kvh = k_cache.shape[2]
+    qg = q.reshape(b, t, kvh, h // kvh, d)
+    k = constrain(k_cache, ("batch", "kv_seq", None, None))
+    v = constrain(v_cache, ("batch", "kv_seq", None, None))
+    s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k, preferred_element_type=jnp.float32)
+    s = constrain(s / jnp.sqrt(d), ("batch", None, None, None, "kv_seq"))
+    valid = jnp.arange(k_cache.shape[1])[None, :] <= qpos[:, None]  # (T, Smax)
+    s = jnp.where(valid, s, NEG_INF)
+    p = constrain(jax.nn.softmax(s, axis=-1), ("batch", None, None, None, "kv_seq"))
+    out = jnp.einsum("bkgqs,bskd->bqkgd", p, v)
+    out = constrain(out.reshape(b, t, h, v_cache.shape[-1]), ("batch", None, None, None))
+    return out.astype(q.dtype)
+
+
 def attention_decode(q, k_cache, v_cache, pos) -> jax.Array:
     """q: (B,1,H,D); caches: (B,Smax,KV,D); pos: scalar index of the new token.
 
-    Attends over cache[0..pos] inclusive (cache already updated at pos).
+    Attends over cache[0..pos] inclusive (cache already updated at pos),
+    each KV head against its group of H // KV query heads in place
+    (``_grouped_attention``): the cache is never repeated to H heads nor
+    copied to f32.
 
     Flash-decoding dataflow: the cache's SEQUENCE axis is the sharded one
-    ("kv_seq" → "model"), so every intermediate that carries the sequence
-    axis is pinned to that sharding — without the pins, GSPMD propagates the
-    output projection's heads-sharding backwards and re-shards (= fully
-    all-gathers) the repeated K/V cache, which dominates the decode step
-    (measured: 2×67 MB × layers per step on granite-3-8b × 32k). The only
-    collectives left are the softmax partials and the (B,1,H,D) output
-    all-reduce.
+    ("kv_seq" → "model"), so K/V stay pinned to ("batch", "kv_seq", ·, ·)
+    and the (B,KV,G,1,Smax) scores and probabilities to ("batch", ·, ·, ·,
+    "kv_seq") — without the pins, GSPMD propagates the output projection's
+    heads-sharding backwards and re-shards (= fully all-gathers) the K/V
+    cache, which dominates the decode step (measured: 2×67 MB × layers per
+    step on granite-3-8b × 32k). The only collectives left are the softmax
+    partials and the (B,1,H,D) output all-reduce.
     """
     with jax.named_scope("attention"):
-        b, _, h, d = q.shape
-        kvh = k_cache.shape[2]
-        g = h // kvh
-        qf = q.astype(jnp.float32)
-        k = constrain(_repeat_kv(k_cache, g), ("batch", "kv_seq", None, None))
-        v = constrain(_repeat_kv(v_cache, g), ("batch", "kv_seq", None, None))
-        s = jnp.einsum("bqhd,bkhd->bhqk", qf, k.astype(jnp.float32))
-        s = constrain(s / jnp.sqrt(d), ("batch", None, None, "kv_seq"))
-        valid = jnp.arange(k_cache.shape[1]) <= pos
-        s = jnp.where(valid[None, None, None, :], s, NEG_INF)
-        p = constrain(jax.nn.softmax(s, axis=-1), ("batch", None, None, "kv_seq"))
-        out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
-        out = constrain(out, ("batch", None, None, None))
-        return out.astype(q.dtype)
+        return _grouped_attention(q, k_cache, v_cache, jnp.reshape(pos, (1,)))
 
 
 def run_attention(cfg: ArchConfig, q, k, v, *, causal: bool) -> jax.Array:
@@ -289,7 +305,9 @@ def attention_chunk(q, k_cache, v_cache, pos) -> jax.Array:
 
     Each query attends causally over cache[0..pos+i]; rows past the written
     prefix are dead data and masked out. This is ``attention_decode``
-    generalized from one query to a chunk of T.
+    generalized from one query to a chunk of T, through the same grouped
+    contraction and sharding pins (``_grouped_attention``): scores are
+    (B,KV,G,T,Smax) in f32, and K/V are read in place.
 
     The strict positional mask is also what makes speculative verify
     windows rollback-free for attention caches: rows written for REJECTED
@@ -298,19 +316,7 @@ def attention_chunk(q, k_cache, v_cache, pos) -> jax.Array:
     the slot's position, no cache surgery (``models.model.decode_verify``).
     """
     with jax.named_scope("attention"):
-        b, t, h, d = q.shape
-        kvh = k_cache.shape[2]
-        g = h // kvh
-        qf = q.astype(jnp.float32)
-        k = _repeat_kv(k_cache, g)
-        v = _repeat_kv(v_cache, g)
-        s = jnp.einsum("bqhd,bkhd->bhqk", qf, k.astype(jnp.float32)) / jnp.sqrt(d)
-        qpos = pos + jnp.arange(t)
-        valid = jnp.arange(k_cache.shape[1])[None, :] <= qpos[:, None]  # (T, Smax)
-        s = jnp.where(valid[None, None], s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
-        return out.astype(q.dtype)
+        return _grouped_attention(q, k_cache, v_cache, pos + jnp.arange(q.shape[1]))
 
 
 def gqa_chunk_apply(params, x, cache_k, cache_v, pos, cfg: ArchConfig, *, rope: bool = True):

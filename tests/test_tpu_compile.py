@@ -14,12 +14,14 @@ from __future__ import annotations
 import dataclasses
 import importlib.util
 import os
+import re
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels.flash_attention import flash_attention
@@ -27,11 +29,14 @@ from repro.kernels.int8_matmul import int8_matmul
 from repro.kernels.lstm_quant import quantize_lstm_weights
 from repro.kernels.lstm_seq import (lstm_seq_fused, lstm_seq_fused_quantized,
                                     lstm_stack_fused)
+from repro.models.layers import gqa_decode_apply, gqa_defs
 from repro.models.model import init_model
 from repro.models.params import init_params
 from repro.serving.engine import InferenceEngine, ServeConfig
 from repro.serving.kv_cache import cache_defs, page_defs, paged_cache_bytes
 from repro.serving.pages import PagedSlotPool
+from repro.sharding.rules import (DATA, MODEL, activate_mesh, sharding_for,
+                                  tensor_parallel_rules)
 
 _spec = importlib.util.spec_from_file_location(
     "chip_smoke", Path(__file__).resolve().parent.parent / "chip_smoke.py")
@@ -138,12 +143,7 @@ def test_flash_attention_compiles(one_chip):
     _fits(compiled)
 
 
-@pytest.mark.parametrize("layers", [smoke.NUMERICS_LAYERS, smoke.SERVE_LAYERS])
-def test_paged_decode_compiles_at_granite_widths(one_chip, layers):
-    """The serving decode step at published widths with the smoke's pool:
-    16 slots, 4096 positions, parity-sized page pool. At 16 layers it fits
-    only because the model gathers each slot's pages one layer at a time
-    (``models.model.PagedRows``); a whole-stack gather needs 8 GiB more."""
+def _paged_decode_step(one_chip, layers):
     cfg = dataclasses.replace(get_config("granite-3-8b"), num_layers=layers)
     sc = ServeConfig(max_batch=16, max_len=4096, paged=True)
     key = jax.random.PRNGKey(0)
@@ -159,13 +159,10 @@ def test_paged_decode_compiles_at_granite_widths(one_chip, layers):
     args = (params, pool, vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
             jax.ShapeDtypeStruct((sc.max_batch, mb), jnp.int32))
     specs = jax.tree.map(lambda x: _spec_of(x, one_chip), args)
-    _fits(engine._paged_decode.lower(*specs).compile())
+    return cfg, mb * sc.page_size, engine._paged_decode.lower(*specs).compile()
 
 
-def test_chunk_step_fits_beside_the_page_pool(one_chip):
-    """A chunked-prefill step of a 4-request group (the smoke's traffic
-    keeps groups smaller) at 16 layers, 256-token chunks, with the serving
-    page pool resident beside it."""
+def _chunk_step(one_chip):
     cfg = dataclasses.replace(get_config("granite-3-8b"),
                               num_layers=smoke.SERVE_LAYERS)
     sc = ServeConfig(max_batch=16, max_len=4096, paged=True)
@@ -178,8 +175,84 @@ def test_chunk_step_fits_beside_the_page_pool(one_chip):
     args = (engine.params, group, jax.ShapeDtypeStruct((4, 256), jnp.int32),
             jax.ShapeDtypeStruct((), jnp.int32), None)
     specs = jax.tree.map(lambda x: _spec_of(x, one_chip), args)
-    compiled = engine._chunk.lower(*specs).compile()
     pool_bytes = paged_cache_bytes(cfg, batch=sc.max_batch,
                                    num_pages=sc.max_batch * mb + 1,
                                    page_size=sc.page_size, max_blocks=mb)
+    return cfg, mb * sc.page_size, engine._chunk.lower(*specs).compile(), pool_bytes
+
+
+@pytest.mark.parametrize("layers", [smoke.NUMERICS_LAYERS, smoke.SERVE_LAYERS])
+def test_paged_decode_compiles_at_granite_widths(one_chip, layers):
+    """The serving decode step at published widths with the smoke's pool:
+    16 slots, 4096 positions, parity-sized page pool. At 16 layers it fits
+    only because the model gathers each slot's pages one layer at a time
+    (``models.model.PagedRows``); a whole-stack gather needs 8 GiB more."""
+    _fits(_paged_decode_step(one_chip, layers)[2])
+
+
+def _arrays(hlo: str):
+    """(dtype, dims) of every array type named in compiled HLO text."""
+    return {(dt, tuple(int(n) for n in dims.split(",") if n))
+            for dt, dims in re.findall(r"\b(bf16|f16|f32|s8)\[([0-9,]*)\]", hlo)}
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_attention_reads_the_cache_in_place(one_chip, step):
+    """Serving attention contracts each KV head with its query group in
+    place: no array of the compiled step carries the slot's whole virtual
+    row together with the head dim and all query heads (a K/V repeated to
+    32 heads, as ``[..., rows, 32, 128]`` or ``[..., rows, 8, 4, 128]``),
+    and no K/V row is copied to f32. Scores carry rows and heads but no
+    head dim, so they pass."""
+    if step == "decode":
+        cfg, rows, compiled = _paged_decode_step(one_chip, smoke.SERVE_LAYERS)
+    else:
+        cfg, rows, compiled, _ = _chunk_step(one_chip)
+    h, kvh = cfg.num_heads, cfg.num_kv_heads
+    d = cfg.resolved_head_dim
+    arrays = _arrays(compiled.as_text())
+    row_arrays = [(dt, dims) for dt, dims in arrays if rows in dims and d in dims]
+    assert row_arrays, "the step reads no K/V row at all"
+    for dt, dims in row_arrays:
+        grouped = any(dims[i:i + 2] == (kvh, h // kvh) for i in range(len(dims)))
+        assert h not in dims and not grouped, f"K/V repeated to all heads: {dt}{list(dims)}"
+        assert dt != "f32", f"f32 copy of a K/V row: {dt}{list(dims)}"
+
+
+def test_chunk_step_fits_beside_the_page_pool(one_chip):
+    """A chunked-prefill step of a 4-request group (the smoke's traffic
+    keeps groups smaller) at 16 layers, 256-token chunks, with the serving
+    page pool resident beside it."""
+    _, _, compiled, pool_bytes = _chunk_step(one_chip)
     assert _fits(compiled) + pool_bytes <= USABLE_HBM_BYTES
+
+
+def test_decode_attention_keeps_the_cache_sequence_sharded(topo):
+    """Flash-decoding under a 4-chip mesh: the K/V cache is sharded along
+    its sequence axis, and one granite decode attention block adds no
+    collective that carries that axis (an all-gather of the cache would);
+    only the single token's q/k/v, the softmax partials and the output
+    cross chips."""
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), (DATA, MODEL))
+    rules = tensor_parallel_rules()
+    cfg = get_config("granite-3-8b")
+    batch, seq = 4, 2048
+    params = {k: jax.ShapeDtypeStruct(d.shape, jnp.bfloat16,
+                                      sharding=sharding_for(d, mesh, rules))
+              for k, d in gqa_defs(cfg).items()}
+    cache = jax.ShapeDtypeStruct(
+        (batch, seq, cfg.num_kv_heads, cfg.resolved_head_dim), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(None, MODEL, None, None)))
+    replicated = NamedSharding(mesh, P())
+    x = jax.ShapeDtypeStruct((batch, 1, cfg.d_model), jnp.bfloat16,
+                             sharding=replicated)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated)
+    with activate_mesh(mesh, rules):
+        hlo = jax.jit(lambda p, x, k, v, pos: gqa_decode_apply(
+            p, x, k, v, pos, cfg)).lower(params, x, cache, cache, pos).compile().as_text()
+    ops = "all-gather|all-reduce|all-to-all|collective-permute|reduce-scatter"
+    moved = re.findall(rf"= \w+\[([0-9,]*)\]\S* (?:{ops})(?:-start)?\(", hlo)
+    assert moved, "no collective at all: the mesh did not take"
+    for dims in moved:
+        sizes = [int(n) for n in dims.split(",")]
+        assert seq not in sizes and seq // 4 not in sizes, f"cache moved: [{dims}]"
