@@ -83,36 +83,30 @@ def at_size(cell: Cell, size: str) -> tuple[dict, dict, dict]:
     if size == "cpu_test":
         t = cell.conf["cpu_test"]
         model = {**model, **t["model"]}
-        if "ssm" in t:
-            model["ssm"] = {**model["ssm"], **t["ssm"]}
+        for k, v in t.items():  # a nested group of the model's own sizes
+            if isinstance(model.get(k), dict):
+                model[k] = {**model[k], **v}
         serving = {**serving, **t["serving"]}
         mix = {**mix, **t["traffic"]}
     return model, serving, mix
 
 
 # -- the program's side ---------------------------------------------------------
-def program_config(conf: dict, model: dict, quant: str | None = None):
-    """The program's ArchConfig for ``model``, checked field by field."""
+def program_config(conf: dict, model: dict, fam, quant: str | None = None):
+    """The program's ArchConfig for ``model``, checked field by field; the
+    family module ``fam`` sets the fields of its own layers."""
     from repro.configs import get_config
-    from repro.configs.base import SSMConfig
 
     base = get_config(conf["arch"])
     fields = {k: model[k] for k in ("num_layers", "d_model", "num_heads",
                                     "num_kv_heads", "d_ff", "vocab_size",
                                     "norm_eps") if k in model}
     fields["tie_embeddings"] = model["tie_embeddings"]
-    if model["family"] == "dense":
-        fields["head_dim"] = model["head_dim"]
-        fields["rope_theta"] = model["rope_theta"]
-    else:
-        s = model["ssm"]
-        fields["ssm"] = SSMConfig(state_size=s["state_size"], head_dim=s["head_dim"],
-                                  expand=s["expand"], conv_width=s["conv_width"],
-                                  chunk_size=s["chunk_size"])
+    fields.update(fam.program_fields(model))
     cfg = dataclasses.replace(base, **fields, quant=quant)
-    if cfg.family != model["family"]:
-        raise ValueError(f"{conf['arch']} is family {cfg.family}, the file says "
-                         f"{model['family']}")
+    if cfg.family != fam.PROGRAM_FAMILY:
+        raise ValueError(f"{conf['arch']} is family {cfg.family}; {fam.__file__} "
+                         f"serves {fam.PROGRAM_FAMILY}")
     return cfg
 
 
@@ -201,6 +195,7 @@ class Run:
     waits: list
     setup_s: float
     m: dict
+    fam: object       # the model's family module (bench/families)
     peaks: dict | None
     trace: dict | None
 
@@ -361,16 +356,18 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     dev, devs, pk = device_info(require_tpu, cell.chips)
     compiles = _compile_counter()
     from repro.serving.load import Request
-    from bench import reference, weights
+    from bench import families, reference, weights
     from bench.spans import Recorder
     from bench.wallclock import WallClock
 
     # -- set-up: weights, the server, every compiled shape ---------------------
-    cfg = program_config(cell.conf, model, quant=control)
-    check_layout(weights.abstract(model), cfg)
+    fam = families.module(model["family"], root / "bench" / "families")
+    ref = reference.module(cell.conf["reference"], root / "bench" / "reference")
+    cfg = program_config(cell.conf, model, fam, quant=control)
+    check_layout(weights.abstract(model, fam), cfg)
     clock = WallClock(seconds)
     # the control serves as the launcher's --quant-weights --quant-kv does
-    engine, sched = build(cell.conf["arch"], cfg, [weights.make(model, seed, dev)],
+    engine, sched = build(cell.conf["arch"], cfg, [weights.make(model, seed, dev, fam)],
                           serving, clock, seed, kv_quant=control)
     params = None if control else engine.params
     # ``fault(engine)`` breaks the timed path underneath (tests only); it may
@@ -416,8 +413,8 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         log(f"trace: window {reduced['window_s']!r} s, busy {reduced['busy_s']!r} s, "
             f"module runs {json.dumps(reduced['kinds'])}")
 
-    rd = Run(rec, items, start_s, float(seconds), end_s, clock.waits, setup_s, model, pk,
-             reduced)
+    rd = Run(rec, items, start_s, float(seconds), end_s, clock.waits, setup_s, model, fam,
+             pk, reduced)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         v = reader(m["name"], root / "bench" / "metrics")(rd)
@@ -428,9 +425,8 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     free_program_state(engine, sched)
     del engine, sched
     if control:
-        params = weights.make(model, seed, dev)
-    correct, compared = _check(rec, items, params, model, mix, cc,
-                               reference.module(cell.conf["reference"]), seed, log)
+        params = weights.make(model, seed, dev, fam)
+    correct, compared = _check(rec, items, params, model, mix, cc, ref, seed, log)
 
     device = {"platform": dev.platform, "kind": dev.device_kind, "count": len(devs),
               "memory_peak_bytes": peak}

@@ -7,18 +7,13 @@ the end-to-end metric it moves keeps one reader. ``run`` is the
 ``harness.Run`` of the finished window. A reader that finds nothing to read
 returns None and the metric is left out of the line.
 """
-import importlib.util
 from pathlib import Path
+
+from bench import found
 
 HERE = Path(__file__).resolve().parent
 
 
 def reader(name: str, base: Path = HERE):
-    for stem in (name, name.split(".")[0]):
-        path = base / f"{stem}.py"
-        if path.exists():
-            spec = importlib.util.spec_from_file_location(f"bench_metric_{stem}", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod.read
-    raise FileNotFoundError(f"no reader for metric {name!r} under {base}")
+    stem = name if (base / f"{name}.py").exists() else name.split(".")[0]
+    return found.module(base, stem, "metric reader").read

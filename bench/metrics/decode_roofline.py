@@ -8,6 +8,6 @@ def read(run):
     k = run.trace and run.trace["kinds"]["decode"]
     if not k or not k["calls"]:
         return None
-    least = sum(costs.least_seconds(*costs.decode(run.m, s.positions), run.peaks)
+    least = sum(costs.least_seconds(*costs.decode(run.m, s.positions, run.fam), run.peaks)
                 for s in run.rec.of("decode"))
     return 100.0 * least / k["device_s"]

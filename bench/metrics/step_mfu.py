@@ -11,7 +11,7 @@ def read(run):
     dev = k["decode"]["device_s"] + k["chunk"]["device_s"]
     if dev <= 0:
         return None
-    flops = sum(costs.decode(run.m, s.positions)[0] for s in run.rec.of("decode"))
-    flops += sum(costs.chunk(run.m, s.rows, s.positions[0], s.tokens)[0]
+    flops = sum(costs.decode(run.m, s.positions, run.fam)[0] for s in run.rec.of("decode"))
+    flops += sum(costs.chunk(run.m, s.rows, s.positions[0], s.tokens, run.fam)[0]
                  for s in run.rec.of("chunk"))
     return 100.0 * flops / (dev * run.peaks["bf16_flops_per_s"])

@@ -1,11 +1,17 @@
-"""A new workload, configuration, traffic mix or metric is a file found by
-its name: the harness takes one without a code edit."""
+"""A new workload, configuration, traffic mix, metric or model family is a
+file found by its name under the benchmark root: the harness takes one
+without a code edit. A model family is its module under ``bench/families``
+(weight layout, costs, program fields) and its reference under
+``bench/reference``; the harness's own files name no family."""
 import json
+import re
 import shutil
 import subprocess
 import sys
 import time
 from pathlib import Path
+
+import pytest
 
 from bench import harness
 from bench.metrics import reader
@@ -15,8 +21,9 @@ ROOT = Path(__file__).resolve().parents[2]
 
 def _copy_benchmark(tmp: Path) -> dict:
     shutil.copy(ROOT / "BENCHMARK.json", tmp / "BENCHMARK.json")
-    for d in ("configs", "traffic", "metrics"):
-        shutil.copytree(ROOT / "bench" / d, tmp / "bench" / d)
+    for d in ("configs", "traffic", "metrics", "families", "reference"):
+        shutil.copytree(ROOT / "bench" / d, tmp / "bench" / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     return json.loads((tmp / "BENCHMARK.json").read_text())
 
 
@@ -47,6 +54,47 @@ def test_new_files_are_found_by_name(tmp_path):
                       size="cpu_test", require_tpu=False, root=tmp_path,
                       log=lambda s: None)
     assert out["metrics"]["served_requests"]["value"] > 0
+
+
+def _add_granite_of_family(tmp: Path, bj: dict, family: str) -> str:
+    """A configuration of granite's sizes whose model names ``family``, and a
+    cell of it; returns the cell's name."""
+    b = tmp / "bench"
+    conf = json.loads((b / "configs" / "granite-3-8b-16L.json").read_text())
+    conf["name"] = f"granite-{family}"
+    conf["model"]["family"] = conf["reference"] = family
+    (b / "configs" / f"granite-{family}.json").write_text(json.dumps(conf))
+    bj["workloads"].append({"name": f"{family}-backlog", "config": f"granite-{family}",
+                            "traffic": "decode-backlog", "chips": 1, "why": "test"})
+    (tmp / "BENCHMARK.json").write_text(json.dumps(bj))
+    return f"{family}-backlog"
+
+
+def test_new_family_is_found_by_name(tmp_path):
+    """A family added as two files in a copied root, a copy of ``dense``
+    under a new name that serves the program's dense family, is served and
+    checked with no other file touched."""
+    bj = _copy_benchmark(tmp_path)
+    b = tmp_path / "bench"
+    for d in ("families", "reference"):
+        shutil.copy(b / d / "dense.py", b / d / "dense_copy.py")
+        assert not (ROOT / "bench" / d / "dense_copy.py").exists()
+    cell = _add_granite_of_family(tmp_path, bj, "dense_copy")
+    out = harness.run(cell, 5, 1.5, False, t_process=time.perf_counter(),
+                      size="cpu_test", require_tpu=False, root=tmp_path,
+                      log=lambda s: None)
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["compared"]["max_logit_gap"]["value"] <= out["compared"]["max_logit_gap"]["limit"]
+
+
+def test_family_without_a_file_names_the_file(tmp_path):
+    bj = _copy_benchmark(tmp_path)
+    cell = _add_granite_of_family(tmp_path, bj, "no_such_family")
+    path = (tmp_path / "bench" / "families" / "no_such_family.py").resolve()
+    with pytest.raises(FileNotFoundError, match=re.escape(str(path))):
+        harness.run(cell, 5, 1.5, False, t_process=time.perf_counter(),
+                    size="cpu_test", require_tpu=False, root=tmp_path,
+                    log=lambda s: None)
 
 
 def test_split_metric_is_read_by_its_base_reader():
