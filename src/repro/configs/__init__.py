@@ -17,6 +17,7 @@ from repro.configs.base import (  # noqa: F401
 from repro.configs import (  # noqa: F401
     deepseek_v3_671b,
     granite_3_8b,
+    granite_4_0_h_small,
     granite_34b,
     granite_moe_3b_a800m,
     internvl2_76b,

@@ -39,6 +39,16 @@ class MoEConfig:
     # 16-way "model" axis; padding experts are masked in the router).
     ep_axes: tuple[str, ...] = ("model",)
     padded_experts: int = 0
+    # The experts this chip holds, [first_expert, first_expert +
+    # experts_held), when an expert-parallel deployment divides them over
+    # chips (0 = all of them). The router still scores all ``num_experts``
+    # and takes its ``top_k``; only the held experts add to the output.
+    experts_held: int = 0
+    first_expert: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.padded_experts or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,7 +78,7 @@ class SSMConfig:
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str  # dense | moe | ssm | hybrid | vlm | audio
+    family: str  # dense | moe | ssm | hybrid | hybrid_moe | vlm | audio
     num_layers: int
     d_model: int
     num_heads: int
@@ -87,6 +97,17 @@ class ArchConfig:
     mtp: bool = False  # deepseek multi-token-prediction head
     ssm: SSMConfig | None = None
     attn_every: int = 0  # hybrid: shared attention applied every k-th layer
+    # hybrid_moe: the layers whose mixer is attention (the rest are Mamba-2)
+    attn_layers: tuple[int, ...] = ()
+    rope: bool = True  # False: attention takes no position embedding (NoPE)
+    # Granite's multipliers (hybrid_moe): the embedding is scaled by
+    # embedding_multiplier, attention scores by attention_multiplier (0 =
+    # 1/sqrt(head_dim)), each residual branch by residual_multiplier, and the
+    # logits divided by logits_scaling
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     encoder_layers: int = 0  # enc-dec (whisper)
     encoder_seq: int = 0  # frames from the stubbed conv frontend
     frontend: str | None = None  # "audio" | "vision" stub (precomputed embeds)
@@ -117,6 +138,11 @@ class ArchConfig:
         return self.head_dim or self.d_model // self.num_heads
 
     @property
+    def attention_layers(self) -> tuple[int, ...]:
+        """hybrid_moe: the attention layers among the first ``num_layers``."""
+        return tuple(i for i in self.attn_layers if i < self.num_layers)
+
+    @property
     def padded_vocab(self) -> int:
         """Embedding-table vocab rounded up to a multiple of 256 so the vocab
         axis TP-shards on any mesh; padded logits are masked at the CE /
@@ -145,8 +171,11 @@ class ArchConfig:
         epad = m.padded_experts or m.num_experts
         expert_p = 3 * self.d_model * m.expert_d_ff  # gate/up/down
         n_moe_layers = self.num_layers - self.first_k_dense
-        inactive = n_moe_layers * (epad - m.top_k) * expert_p
-        return total - inactive
+        # a share of the experts holds top_k * held / num_experts active ones
+        # per token on average
+        active = m.top_k if m.held == epad else m.top_k * m.held / m.num_experts
+        inactive = n_moe_layers * (m.held - active) * expert_p
+        return int(total - inactive)
 
 
 _REGISTRY: dict[str, Callable[[], ArchConfig]] = {}

@@ -30,7 +30,8 @@ Memory (any scheduler mode):
   --share-prefix    copy-on-write shared-prefix reuse: admissions whose
                     prompt matches a registered block-aligned prefix map
                     the resident pages read-only and prefill only the delta
-                    (paged only; disabled for SSM/hybrid/frontend families)
+                    (paged only; disabled for recurrent-state and frontend
+                    families)
   --page-budget     override the physical page count (default: contiguous
                     parity); smaller budgets over-commit the pool and
                     exercise the watermark/preemption path

@@ -319,13 +319,19 @@ def attention_chunk(q, k_cache, v_cache, pos) -> jax.Array:
         return _grouped_attention(q, k_cache, v_cache, pos + jnp.arange(q.shape[1]))
 
 
-def gqa_chunk_apply(params, x, cache_k, cache_v, pos, cfg: ArchConfig, *, rope: bool = True):
+def gqa_chunk_apply(params, x, cache_k, cache_v, pos, cfg: ArchConfig, *, rope: bool = True,
+                    q_scale: float = 1.0):
     """Chunked-prefill attention: T prompt tokens appended at ``pos``.
 
     x: (B,T,D). Returns (out, k_cache, v_cache) with the chunk's K/V written
-    into the cache span [pos, pos+T)."""
+    into the cache span [pos, pos+T). ``q_scale`` multiplies the queries
+    (in f32, before they are rounded back), so the scores are scaled by
+    ``q_scale / sqrt(head_dim)``."""
     positions = (pos + jnp.arange(x.shape[1]))[None, :]
     q, k_new, v_new = gqa_project_qkv(params, x, cfg, positions, rope=rope)
+    if q_scale != 1.0:
+        with jax.named_scope("proj"):
+            q = (q.astype(jnp.float32) * q_scale).astype(q.dtype)
     k_cache = write_cache_span(cache_k, k_new, pos)
     v_cache = write_cache_span(cache_v, v_new, pos)
     out = attention_chunk(q, k_cache, v_cache, pos)

@@ -16,6 +16,10 @@ Family wiring:
   hybrid (zamba2)  segments of ``attn_every`` Mamba2 layers, each preceded by
                    the ONE weight-shared attention block (14 applications for
                    81 layers / every 6)
+  hybrid_moe       Mamba2 layers and NoPE attention layers (by index,
+  (granite-4.0-h)  ``cfg.attn_layers``), each followed by routed + shared
+                   experts; one body over T tokens against the cache
+                   (``_hybrid_moe_stack``) serves all four entry points
   audio (whisper)  encoder stack over stubbed frames + causal decoder with
                    cross-attention; sinusoidal positions; tied unembedding
 
@@ -72,6 +76,13 @@ def param_defs(cfg: ArchConfig) -> dict:
     elif f == "hybrid":
         defs["blocks"] = stacked(cfg.num_layers, T.ssm_block_defs(cfg))
         defs["shared"] = T.shared_attn_defs(cfg)
+    elif f == "hybrid_moe":
+        n_attn = len(cfg.attention_layers)
+        defs["blocks"] = {
+            "mamba": stacked(cfg.num_layers - n_attn, T.ssm_block_defs(cfg)),
+            "attn": stacked(n_attn, T.attn_mixer_defs(cfg)),
+            "ffn": stacked(cfg.num_layers, T.moe_ffn_defs(cfg)),
+        }
     elif f == "audio":
         defs["enc_blocks"] = stacked(cfg.encoder_layers, T.enc_block_defs(cfg))
         defs["enc_norm"] = T.norm_defs(cfg)
@@ -182,11 +193,56 @@ def _stack_slice(stack, start, length):
     return jax.tree.map(lambda t: jax.lax.slice_in_dim(t, start, start + length, axis=0), stack)
 
 
+def _hybrid_moe_stack(params, cache, tokens, pos, cfg: ArchConfig, *, verify=False):
+    """The hybrid_moe family's one body: T tokens at positions [pos, pos+T)
+    against a cache of Mamba-2 rows (``conv``, ``state``) and attention rows
+    (``k``, ``v``; arrays, or ``PagedRows`` gathered a layer at a time).
+    ``forward`` and ``prefill`` run it over a zero cache from position 0,
+    ``decode_step`` at T=1, ``prefill_chunk`` and ``decode_verify`` as they
+    come. Returns (hidden states before the final norm, cache)."""
+    blocks = params["blocks"]
+    x = embed_apply(params["embed"], tokens, cfg)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    x = constrain(x, ("batch", None, None))
+    new = {"conv": [], "state": [], "k": [], "v": []}
+    i_attn = i_mamba = 0
+    for i in range(cfg.num_layers):
+        ffn = _layer(blocks["ffn"], i)
+        if i in cfg.attn_layers:
+            kc, vc = cache["k"][i_attn], cache["v"][i_attn]
+            x, (k, v) = T.hybrid_moe_layer(
+                _layer(blocks["attn"], i_attn), ffn, x, (_cache_view(kc), _cache_view(vc)),
+                pos, cfg, attn=True)
+            new["k"].append(_cache_written(k, kc, pos))
+            new["v"].append(_cache_written(v, vc, pos))
+            i_attn += 1
+        else:
+            x, (conv, state) = T.hybrid_moe_layer(
+                _layer(blocks["mamba"], i_mamba), ffn, x,
+                (cache["conv"][i_mamba], cache["state"][i_mamba]), pos, cfg,
+                attn=False, verify=verify)
+            new["conv"].append(conv)
+            new["state"].append(state)
+            i_mamba += 1
+    return x, {k: jnp.stack(v) for k, v in new.items()}
+
+
+def _zero_cache(cfg: ArchConfig, batch: int, max_len: int) -> dict:
+    from repro.serving.kv_cache import cache_defs
+
+    return init_params(cache_defs(cfg, batch=batch, max_len=max_len), jax.random.PRNGKey(0))
+
+
 # ---------------------------------------------------------------------------
 # Forward (train path) → final hidden states
 # ---------------------------------------------------------------------------
 def forward(params, tokens, cfg: ArchConfig, frontend_embeds=None):
     f = cfg.family
+    if f == "hybrid_moe":
+        b, s = tokens.shape
+        x, _ = _hybrid_moe_stack(params, _zero_cache(cfg, b, s), tokens, 0, cfg)
+        return T.apply_norm(cfg, params["final_norm"], x), ZERO
     if f == "audio":
         enc = _encode_audio(params, cfg, frontend_embeds)
         x = _embed_tokens(params, tokens, cfg)
@@ -229,6 +285,8 @@ def forward(params, tokens, cfg: ArchConfig, frontend_embeds=None):
 def _ce_block(params, hidden, labels, mask, cfg: ArchConfig):
     """CE over one block. hidden: (B,T,D), labels/mask: (B,T). Returns (nll_sum, n)."""
     logits = unembed_apply(params["embed"], hidden, cfg).astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     v = logits.shape[-1]
     if v > cfg.vocab_size:  # mask the vocab-padding columns out of the lse
         logits = jnp.where(jnp.arange(v)[None, None, :] < cfg.vocab_size, logits, -1e30)
@@ -300,7 +358,10 @@ def _encode_audio(params, cfg: ArchConfig, frontend_embeds):
 def prefill(params, tokens, cfg: ArchConfig, frontend_embeds=None):
     f = cfg.family
     cache: dict[str, Any] = {}
-    if f == "audio":
+    if f == "hybrid_moe":
+        b, s = tokens.shape
+        x, cache = _hybrid_moe_stack(params, _zero_cache(cfg, b, s), tokens, 0, cfg)
+    elif f == "audio":
         enc = _encode_audio(params, cfg, frontend_embeds)
         x = _embed_tokens(params, tokens, cfg)
         x, (k, v, ck, cv) = run_stack_prefill(
@@ -394,8 +455,10 @@ def prefill(params, tokens, cfg: ArchConfig, frontend_embeds=None):
 def decode_step(params, cache, token, pos, cfg: ArchConfig):
     """token: (B, 1) int32; pos: scalar int32 (position being written)."""
     f = cfg.family
-    x = embed_apply(params["embed"], token, cfg)
-    if f == "audio":
+    x = None if f == "hybrid_moe" else embed_apply(params["embed"], token, cfg)
+    if f == "hybrid_moe":
+        x, cache = _hybrid_moe_stack(params, cache, token, pos, cfg)
+    elif f == "audio":
         pe = T.sinusoid_positions(1, cfg.d_model, offset=pos).astype(x.dtype)
         x = x + pe[None]
         x, (k, v, ck, cv) = run_stack_decode(
@@ -486,18 +549,19 @@ def encoder_cross_cache(params, cfg: ArchConfig, frontend_embeds):
 
 
 def _chunk_forward(params, cache, tokens, pos, cfg: ArchConfig, frontend_embeds=None,
-                   ssm_block=None):
+                   verify: bool = False):
     """Per-family chunk body shared by ``prefill_chunk`` and ``decode_verify``:
     T tokens against a full-capacity decode cache at positions [pos, pos+T).
     Returns (final hidden states before norm: (B, T, D), cache).
 
-    ``ssm_block`` swaps the ssm/hybrid per-layer body (default
-    ``ssm_block_chunk``); ``decode_verify`` passes ``ssm_block_verify``,
-    whose cache slices carry a per-position snapshot axis for acceptance
-    rollback — everything else about the two paths is identical."""
+    ``verify`` (``decode_verify``) swaps the recurrent per-layer body for
+    one whose cache slices carry a per-position snapshot axis for
+    acceptance rollback (``ssm_block_verify``) — everything else about the
+    two paths is identical."""
     f = cfg.family
-    if ssm_block is None:
-        ssm_block = T.ssm_block_chunk
+    if f == "hybrid_moe":
+        return _hybrid_moe_stack(params, cache, tokens, pos, cfg, verify=verify)
+    ssm_block = T.ssm_block_verify if verify else T.ssm_block_chunk
     x = embed_apply(params["embed"], tokens, cfg)
     if f == "vlm" and frontend_embeds is not None:
         fs = cfg.frontend_seq
@@ -619,12 +683,13 @@ def decode_verify(params, cache, tokens, pos, cfg: ArchConfig, frontend_embeds=N
         ``prefill_chunk`` machinery unchanged — K/V rows for rejected
         candidates are dead data past the committed prefix, masked out by
         position and overwritten by the next verify window. No rollback.
-      * ssm/hybrid recurrent leaves (``conv``/``state``) come back with a
-        per-position axis ((L, B, T, ...) snapshots after every candidate);
-        ``commit_verify`` selects the snapshot at the last accepted token.
+      * recurrent leaves (``conv``/``state``: ssm, hybrid, hybrid_moe) come
+        back with a per-position axis ((L, B, T, ...) snapshots after every
+        candidate); ``commit_verify`` selects the snapshot at the last
+        accepted token.
     """
     x, cache = _chunk_forward(params, cache, tokens, pos, cfg, frontend_embeds,
-                              ssm_block=T.ssm_block_verify)
+                              verify=True)
     with jax.named_scope("logits"):
         hidden = T.apply_norm(cfg, params["final_norm"], x)
         logits = unembed_apply(params["embed"], hidden, cfg)
@@ -637,10 +702,13 @@ def commit_verify(cache, accepted, cfg: ArchConfig):
 
     ``accepted``: traced scalar — number of accepted draft tokens a ∈ [0, K],
     i.e. a+1 tokens of the verify window were really consumed. Attention
-    caches need nothing (rollback is positional); ssm/hybrid recurrent
-    leaves select the per-position snapshot at index a, restoring the
-    ``cache_defs`` layout the next decode/verify step expects."""
-    if cfg.family in ("ssm", "hybrid"):
+    caches need nothing (rollback is positional); recurrent leaves
+    (``kv_cache.has_recurrent_state``) select the per-position snapshot at
+    index a, restoring the ``cache_defs`` layout the next decode/verify step
+    expects."""
+    from repro.serving.kv_cache import has_recurrent_state
+
+    if has_recurrent_state(cfg):
         def take(t):  # (L, B, T, ...) → (L, B, ...) at position ``accepted``
             return jax.lax.dynamic_index_in_dim(t, accepted, axis=2, keepdims=False)
 
@@ -649,9 +717,13 @@ def commit_verify(cache, accepted, cfg: ArchConfig):
 
 
 def _mask_pad_logits(logits, cfg: ArchConfig):
+    """Mask the vocabulary's padding columns, and divide by
+    ``cfg.logits_scaling`` (in float32) where it is not 1."""
     v = logits.shape[-1]
     if v > cfg.vocab_size:
-        return jnp.where(jnp.arange(v) < cfg.vocab_size, logits, -1e30)
+        logits = jnp.where(jnp.arange(v) < cfg.vocab_size, logits, -1e30)
+    if cfg.logits_scaling != 1.0:
+        logits = logits.astype(jnp.float32) / cfg.logits_scaling
     return logits
 
 

@@ -19,6 +19,13 @@ Expert weights are stacked (E_pad, d, f) with the E axis sharded over
 ``cfg.moe.ep_axes``; E is padded (config-time) so every mesh divides it.
 Capacity-overflow tokens are dropped (switch-transformer semantics) via
 scatter ``mode="drop"`` / gather ``mode="fill"``.
+
+An expert share (``MoEConfig.experts_held`` < E): the chip holds experts
+[first_expert, first_expert + held) of an expert-parallel deployment. The
+router keeps its E outputs and its top-k; the dense path computes only the
+held experts' part of the result, for every token it routes to them, and
+drops none. What the absent experts would add is left out: there is no
+exchange on one chip.
 """
 from __future__ import annotations
 
@@ -43,12 +50,12 @@ def _epad(cfg: ArchConfig) -> int:
 
 def moe_defs(cfg: ArchConfig) -> dict:
     m = cfg.moe
-    d, f, ep = cfg.d_model, m.expert_d_ff, _epad(cfg)
+    d, f, ep, held = cfg.d_model, m.expert_d_ff, _epad(cfg), m.held
     defs = {
         "router": ParamDef((d, ep), (None, None), dtype=jnp.float32),
-        "wg": ParamDef((ep, d, f), ("experts", "embed", None)),
-        "wu": ParamDef((ep, d, f), ("experts", "embed", None)),
-        "wd": ParamDef((ep, f, d), ("experts", None, "embed")),
+        "wg": ParamDef((held, d, f), ("experts", "embed", None)),
+        "wu": ParamDef((held, d, f), ("experts", "embed", None)),
+        "wd": ParamDef((held, f, d), ("experts", None, "embed")),
     }
     if m.num_shared:
         shared_f = m.shared_d_ff * m.num_shared
@@ -108,17 +115,22 @@ def _aux_loss(probs, ids, cfg: ArchConfig):
 # dense path (oracle / smoke)
 # ---------------------------------------------------------------------------
 def _moe_dense(params, x, cfg: ArchConfig):
+    """Every held expert on every token, weighted by the token's gate for it
+    (0 where the token did not choose it): exact, and no token dropped."""
     b, s, d = x.shape
     xf = x.reshape(b * s, d)
-    w, ids, probs = _router(params, xf, cfg)
-    ep = _epad(cfg)
-    h = _expert_ffn(
-        params["wg"], params["wu"], params["wd"],
-        jnp.broadcast_to(xf[None], (ep, b * s, d)), cfg,
-    )  # (E, T, D)
-    gates = jnp.zeros((b * s, ep), x.dtype)
-    gates = gates.at[jnp.arange(b * s)[:, None], ids].set(w.astype(x.dtype))
-    y = jnp.einsum("te,etd->td", gates, h)
+    with jax.named_scope("moe"):
+        w, ids, probs = _router(params, xf, cfg)
+        ep, held, first = _epad(cfg), cfg.moe.held, cfg.moe.first_expert
+        h = _expert_ffn(
+            params["wg"], params["wu"], params["wd"],
+            jnp.broadcast_to(xf[None], (held, b * s, d)), cfg,
+        )  # (E_held, T, D)
+        gates = jnp.zeros((b * s, ep), x.dtype)
+        gates = gates.at[jnp.arange(b * s)[:, None], ids].set(w.astype(x.dtype))
+        if held < ep:
+            gates = gates[:, first : first + held]
+        y = jnp.einsum("te,etd->td", gates, h)
     return y.reshape(b, s, d), _aux_loss(probs, ids, cfg)
 
 
@@ -242,7 +254,8 @@ def moe_apply(params, x, cfg: ArchConfig):
     """Returns (y, aux_loss). Picks dense / gather / a2a automatically."""
     mesh = active_mesh()
     m = cfg.moe
-    if mesh is None or math.prod([v for v in mesh.shape.values()]) == 1:
+    if (mesh is None or math.prod([v for v in mesh.shape.values()]) == 1
+            or m.held < _epad(cfg)):  # a share runs its own experts alone
         y, aux = _moe_dense(params, x, cfg)
         if m.num_shared:
             y = y + _shared_ffn(params["shared"], x, cfg)

@@ -18,6 +18,8 @@ Implementation notes:
   * All SSM arithmetic in float32; cast back to activation dtype at the end.
   * ``ssm_reference`` is the sequential oracle used by tests to validate the
     chunked path.
+  * Device scopes: the projections in and out run under ``proj``, the
+    causal convs, the scan and the state update under ``ssm``.
 """
 from __future__ import annotations
 
@@ -220,14 +222,21 @@ def ssm_reference(x, dt, A, Bm, Cm, h0=None):
 # Block application
 # ---------------------------------------------------------------------------
 def _project(params, x, cfg: ArchConfig):
-    s = cfg.ssm
-    h = s.num_heads(cfg.d_model)
-    z = qeinsum("bsd,di->bsi", x, params["wz"])
-    xs = qeinsum("bsd,di->bsi", x, params["wx"])
-    Bm = jnp.einsum("bsd,dn->bsn", x, params["wB"])
-    Cm = jnp.einsum("bsd,dn->bsn", x, params["wC"])
-    dt = jnp.einsum("bsd,dh->bsh", x, params["wdt"])
-    return z, xs, Bm, Cm, dt
+    with jax.named_scope("proj"):
+        z = qeinsum("bsd,di->bsi", x, params["wz"])
+        xs = qeinsum("bsd,di->bsi", x, params["wx"])
+        Bm = jnp.einsum("bsd,dn->bsn", x, params["wB"])
+        Cm = jnp.einsum("bsd,dn->bsn", x, params["wC"])
+        dt = jnp.einsum("bsd,dh->bsh", x, params["wdt"])
+        return z, xs, Bm, Cm, dt
+
+
+def _out(params, y, z, cfg: ArchConfig):
+    """The gated RMSNorm and the output projection."""
+    with jax.named_scope("proj"):
+        y = rmsnorm(params["norm"], y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
+                    cfg.norm_eps)
+        return qeinsum("bsi,id->bsd", y, params["wo"])
 
 
 def mamba_apply(params, x, cfg: ArchConfig):
@@ -236,21 +245,22 @@ def mamba_apply(params, x, cfg: ArchConfig):
     hd, st = s.head_dim, s.state_size
     nh = s.num_heads(cfg.d_model)
     z, xs, Bm, Cm, dt = _project(params, x, cfg)
-    xs = _causal_conv(xs, params["conv_x"], params["conv_x_b"])
-    Bm = _causal_conv(Bm, params["conv_B"], params["conv_B_b"])
-    Cm = _causal_conv(Cm, params["conv_C"], params["conv_C_b"])
-    xs = constrain(xs, ("batch", None, "inner"))
+    with jax.named_scope("ssm"):
+        xs = _causal_conv(xs, params["conv_x"], params["conv_x_b"])
+        Bm = _causal_conv(Bm, params["conv_B"], params["conv_B_b"])
+        Cm = _causal_conv(Cm, params["conv_C"], params["conv_C_b"])
+        xs = constrain(xs, ("batch", None, "inner"))
 
-    b, sl, _ = x.shape
-    xh = xs.reshape(b, sl, nh, hd).astype(jnp.float32)
-    dtf = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
-    A = -jnp.exp(params["A_log"].astype(jnp.float32))
-    y, _ = ssd_chunked(xh, dtf, A, Bm.astype(jnp.float32), Cm.astype(jnp.float32), s.chunk_size)
-    y = y + params["D"][None, None, :, None] * xh
-    y = y.reshape(b, sl, nh * hd).astype(x.dtype)
-    y = constrain(y, ("batch", None, "inner"))
-    y = rmsnorm(params["norm"], y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype), cfg.norm_eps)
-    return qeinsum("bsi,id->bsd", y, params["wo"])
+        b, sl, _ = x.shape
+        xh = xs.reshape(b, sl, nh, hd).astype(jnp.float32)
+        dtf = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
+        A = -jnp.exp(params["A_log"].astype(jnp.float32))
+        y, _ = ssd_chunked(xh, dtf, A, Bm.astype(jnp.float32), Cm.astype(jnp.float32),
+                           s.chunk_size)
+        y = y + params["D"][None, None, :, None] * xh
+        y = y.reshape(b, sl, nh * hd).astype(x.dtype)
+        y = constrain(y, ("batch", None, "inner"))
+    return _out(params, y, z, cfg)
 
 
 def mamba_prefill_apply(params, x, cfg: ArchConfig):
@@ -266,22 +276,23 @@ def mamba_prefill_apply(params, x, cfg: ArchConfig):
     nh = s.num_heads(cfg.d_model)
     w = s.conv_width
     z, xs_raw, B_raw, C_raw, dt = _project(params, x, cfg)
-    tail = jnp.concatenate([xs_raw[:, -(w - 1) :], B_raw[:, -(w - 1) :], C_raw[:, -(w - 1) :]], axis=-1)
-    xs = _causal_conv(xs_raw, params["conv_x"], params["conv_x_b"])
-    Bm = _causal_conv(B_raw, params["conv_B"], params["conv_B_b"])
-    Cm = _causal_conv(C_raw, params["conv_C"], params["conv_C_b"])
+    with jax.named_scope("ssm"):
+        tail = jnp.concatenate([xs_raw[:, -(w - 1) :], B_raw[:, -(w - 1) :],
+                                C_raw[:, -(w - 1) :]], axis=-1)
+        xs = _causal_conv(xs_raw, params["conv_x"], params["conv_x_b"])
+        Bm = _causal_conv(B_raw, params["conv_B"], params["conv_B_b"])
+        Cm = _causal_conv(C_raw, params["conv_C"], params["conv_C_b"])
 
-    b, sl, _ = x.shape
-    xh = xs.reshape(b, sl, nh, hd).astype(jnp.float32)
-    dtf = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
-    A = -jnp.exp(params["A_log"].astype(jnp.float32))
-    y, h_final = ssd_chunked(
-        xh, dtf, A, Bm.astype(jnp.float32), Cm.astype(jnp.float32), s.chunk_size
-    )
-    y = y + params["D"][None, None, :, None] * xh
-    y = y.reshape(b, sl, nh * hd).astype(x.dtype)
-    y = rmsnorm(params["norm"], y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype), cfg.norm_eps)
-    return qeinsum("bsi,id->bsd", y, params["wo"]), tail, h_final
+        b, sl, _ = x.shape
+        xh = xs.reshape(b, sl, nh, hd).astype(jnp.float32)
+        dtf = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
+        A = -jnp.exp(params["A_log"].astype(jnp.float32))
+        y, h_final = ssd_chunked(
+            xh, dtf, A, Bm.astype(jnp.float32), Cm.astype(jnp.float32), s.chunk_size
+        )
+        y = y + params["D"][None, None, :, None] * xh
+        y = y.reshape(b, sl, nh * hd).astype(x.dtype)
+    return _out(params, y, z, cfg), tail, h_final
 
 
 def mamba_chunk_apply(params, x, conv_state, ssm_state, cfg: ArchConfig):
@@ -297,30 +308,30 @@ def mamba_chunk_apply(params, x, conv_state, ssm_state, cfg: ArchConfig):
     nh = s.num_heads(cfg.d_model)
     di = s.d_inner(cfg.d_model)
     z, xs_raw, B_raw, C_raw, dt = _project(params, x, cfg)
+    with jax.named_scope("ssm"):
+        cs_x = conv_state[:, :, :di]
+        cs_B = conv_state[:, :, di : di + st]
+        cs_C = conv_state[:, :, di + st :]
+        xs, cs_x = _conv_chunk(cs_x, xs_raw, params["conv_x"], params["conv_x_b"])
+        Bm, cs_B = _conv_chunk(cs_B, B_raw, params["conv_B"], params["conv_B_b"])
+        Cm, cs_C = _conv_chunk(cs_C, C_raw, params["conv_C"], params["conv_C_b"])
+        new_conv = jnp.concatenate(
+            [cs_x.astype(conv_state.dtype), cs_B.astype(conv_state.dtype),
+             cs_C.astype(conv_state.dtype)],
+            axis=-1,
+        )
 
-    cs_x = conv_state[:, :, :di]
-    cs_B = conv_state[:, :, di : di + st]
-    cs_C = conv_state[:, :, di + st :]
-    xs, cs_x = _conv_chunk(cs_x, xs_raw, params["conv_x"], params["conv_x_b"])
-    Bm, cs_B = _conv_chunk(cs_B, B_raw, params["conv_B"], params["conv_B_b"])
-    Cm, cs_C = _conv_chunk(cs_C, C_raw, params["conv_C"], params["conv_C_b"])
-    new_conv = jnp.concatenate(
-        [cs_x.astype(conv_state.dtype), cs_B.astype(conv_state.dtype), cs_C.astype(conv_state.dtype)],
-        axis=-1,
-    )
-
-    b, sl, _ = x.shape
-    xh = xs.reshape(b, sl, nh, hd).astype(jnp.float32)
-    dtf = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
-    A = -jnp.exp(params["A_log"].astype(jnp.float32))
-    y, h_final = ssd_chunked(
-        xh, dtf, A, Bm.astype(jnp.float32), Cm.astype(jnp.float32), s.chunk_size,
-        h0=ssm_state.astype(jnp.float32),
-    )
-    y = y + params["D"][None, None, :, None] * xh
-    y = y.reshape(b, sl, nh * hd).astype(x.dtype)
-    y = rmsnorm(params["norm"], y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype), cfg.norm_eps)
-    return qeinsum("bsi,id->bsd", y, params["wo"]), new_conv, h_final.astype(ssm_state.dtype)
+        b, sl, _ = x.shape
+        xh = xs.reshape(b, sl, nh, hd).astype(jnp.float32)
+        dtf = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
+        A = -jnp.exp(params["A_log"].astype(jnp.float32))
+        y, h_final = ssd_chunked(
+            xh, dtf, A, Bm.astype(jnp.float32), Cm.astype(jnp.float32), s.chunk_size,
+            h0=ssm_state.astype(jnp.float32),
+        )
+        y = y + params["D"][None, None, :, None] * xh
+        y = y.reshape(b, sl, nh * hd).astype(x.dtype)
+    return _out(params, y, z, cfg), new_conv, h_final.astype(ssm_state.dtype)
 
 
 def mamba_verify_apply(params, x, conv_state, ssm_state, cfg: ArchConfig):
@@ -341,35 +352,34 @@ def mamba_verify_apply(params, x, conv_state, ssm_state, cfg: ArchConfig):
     di = s.d_inner(cfg.d_model)
     w = s.conv_width
     z, xs_raw, B_raw, C_raw, dt = _project(params, x, cfg)
+    with jax.named_scope("ssm"):
+        cs_x = conv_state[:, :, :di]
+        cs_B = conv_state[:, :, di : di + st]
+        cs_C = conv_state[:, :, di + st :]
+        xs, _ = _conv_chunk(cs_x, xs_raw, params["conv_x"], params["conv_x_b"])
+        Bm, _ = _conv_chunk(cs_B, B_raw, params["conv_B"], params["conv_B_b"])
+        Cm, _ = _conv_chunk(cs_C, C_raw, params["conv_C"], params["conv_C_b"])
+        # per-position raw tails: after consuming t+1 tokens the window is rows
+        # [t+1, t+W) of concat(old tail, raw chunk) — position T-1 reproduces
+        # exactly the tail mamba_chunk_apply would carry forward
+        raw = jnp.concatenate([xs_raw, B_raw, C_raw], axis=-1)
+        full = jnp.concatenate([conv_state.astype(raw.dtype), raw], axis=1)
+        sl = x.shape[1]
+        conv_all = jnp.stack(
+            [full[:, t + 1 : t + w, :] for t in range(sl)], axis=1
+        ).astype(conv_state.dtype)
 
-    cs_x = conv_state[:, :, :di]
-    cs_B = conv_state[:, :, di : di + st]
-    cs_C = conv_state[:, :, di + st :]
-    xs, _ = _conv_chunk(cs_x, xs_raw, params["conv_x"], params["conv_x_b"])
-    Bm, _ = _conv_chunk(cs_B, B_raw, params["conv_B"], params["conv_B_b"])
-    Cm, _ = _conv_chunk(cs_C, C_raw, params["conv_C"], params["conv_C_b"])
-    # per-position raw tails: after consuming t+1 tokens the window is rows
-    # [t+1, t+W) of concat(old tail, raw chunk) — position T-1 reproduces
-    # exactly the tail mamba_chunk_apply would carry forward
-    raw = jnp.concatenate([xs_raw, B_raw, C_raw], axis=-1)
-    full = jnp.concatenate([conv_state.astype(raw.dtype), raw], axis=1)
-    sl = x.shape[1]
-    conv_all = jnp.stack(
-        [full[:, t + 1 : t + w, :] for t in range(sl)], axis=1
-    ).astype(conv_state.dtype)
-
-    b = x.shape[0]
-    xh = xs.reshape(b, sl, nh, hd).astype(jnp.float32)
-    dtf = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
-    A = -jnp.exp(params["A_log"].astype(jnp.float32))
-    y, h_all = ssd_states(
-        xh, dtf, A, Bm.astype(jnp.float32), Cm.astype(jnp.float32),
-        ssm_state.astype(jnp.float32),
-    )
-    y = y + params["D"][None, None, :, None] * xh
-    y = y.reshape(b, sl, nh * hd).astype(x.dtype)
-    y = rmsnorm(params["norm"], y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype), cfg.norm_eps)
-    return qeinsum("bsi,id->bsd", y, params["wo"]), conv_all, h_all.astype(ssm_state.dtype)
+        b = x.shape[0]
+        xh = xs.reshape(b, sl, nh, hd).astype(jnp.float32)
+        dtf = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
+        A = -jnp.exp(params["A_log"].astype(jnp.float32))
+        y, h_all = ssd_states(
+            xh, dtf, A, Bm.astype(jnp.float32), Cm.astype(jnp.float32),
+            ssm_state.astype(jnp.float32),
+        )
+        y = y + params["D"][None, None, :, None] * xh
+        y = y.reshape(b, sl, nh * hd).astype(x.dtype)
+    return _out(params, y, z, cfg), conv_all, h_all.astype(ssm_state.dtype)
 
 
 def mamba_decode_apply(params, x, conv_state, ssm_state, cfg: ArchConfig):
@@ -384,32 +394,31 @@ def mamba_decode_apply(params, x, conv_state, ssm_state, cfg: ArchConfig):
     nh = s.num_heads(cfg.d_model)
     di = s.d_inner(cfg.d_model)
     z, xs, Bm, Cm, dt = _project(params, x, cfg)
+    with jax.named_scope("ssm"):
+        cs_x = conv_state[:, :, :di]
+        cs_B = conv_state[:, :, di : di + st]
+        cs_C = conv_state[:, :, di + st :]
+        xs, cs_x = _conv_step(cs_x, xs, params["conv_x"], params["conv_x_b"])
+        Bm, cs_B = _conv_step(cs_B, Bm, params["conv_B"], params["conv_B_b"])
+        Cm, cs_C = _conv_step(cs_C, Cm, params["conv_C"], params["conv_C_b"])
+        new_conv = jnp.concatenate(
+            [cs_x.astype(conv_state.dtype), cs_B.astype(conv_state.dtype),
+             cs_C.astype(conv_state.dtype)],
+            axis=-1,
+        )
 
-    cs_x = conv_state[:, :, :di]
-    cs_B = conv_state[:, :, di : di + st]
-    cs_C = conv_state[:, :, di + st :]
-    xs, cs_x = _conv_step(cs_x, xs, params["conv_x"], params["conv_x_b"])
-    Bm, cs_B = _conv_step(cs_B, Bm, params["conv_B"], params["conv_B_b"])
-    Cm, cs_C = _conv_step(cs_C, Cm, params["conv_C"], params["conv_C_b"])
-    new_conv = jnp.concatenate(
-        [cs_x.astype(conv_state.dtype), cs_B.astype(conv_state.dtype), cs_C.astype(conv_state.dtype)],
-        axis=-1,
-    )
-
-    b = x.shape[0]
-    xh = xs.reshape(b, nh, hd).astype(jnp.float32)
-    dtf = jax.nn.softplus(dt[:, 0].astype(jnp.float32) + params["dt_bias"])  # (B,H)
-    A = -jnp.exp(params["A_log"].astype(jnp.float32))
-    da = jnp.exp(dtf * A)  # (B,H)
-    h_new = ssm_state.astype(jnp.float32) * da[:, :, None, None] + jnp.einsum(
-        "bh,bhp,bn->bhpn", dtf, xh, Bm[:, 0].astype(jnp.float32)
-    )
-    y = jnp.einsum("bhpn,bn->bhp", h_new, Cm[:, 0].astype(jnp.float32))
-    y = y + params["D"][None, :, None] * xh
-    y = y.reshape(b, 1, di).astype(x.dtype)
-    y = rmsnorm(params["norm"], y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype), cfg.norm_eps)
-    out = qeinsum("bsi,id->bsd", y, params["wo"])
-    return out, new_conv, h_new.astype(ssm_state.dtype)
+        b = x.shape[0]
+        xh = xs.reshape(b, nh, hd).astype(jnp.float32)
+        dtf = jax.nn.softplus(dt[:, 0].astype(jnp.float32) + params["dt_bias"])  # (B,H)
+        A = -jnp.exp(params["A_log"].astype(jnp.float32))
+        da = jnp.exp(dtf * A)  # (B,H)
+        h_new = ssm_state.astype(jnp.float32) * da[:, :, None, None] + jnp.einsum(
+            "bh,bhp,bn->bhpn", dtf, xh, Bm[:, 0].astype(jnp.float32)
+        )
+        y = jnp.einsum("bhpn,bn->bhp", h_new, Cm[:, 0].astype(jnp.float32))
+        y = y + params["D"][None, :, None] * xh
+        y = y.reshape(b, 1, di).astype(x.dtype)
+    return _out(params, y, z, cfg), new_conv, h_new.astype(ssm_state.dtype)
 
 
 def conv_channels(cfg: ArchConfig) -> int:

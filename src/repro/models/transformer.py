@@ -8,6 +8,9 @@ Families and their blocks:
   ssm               Mamba2 (SSD) block
   hybrid (zamba2)   Mamba2 stack + ONE weight-shared attention block applied
                     every ``attn_every`` layers (input = concat(x, x0) → proj)
+  hybrid_moe        Mamba2 or NoPE GQA mixer chosen by layer index, each
+  (granite-4.0-h)   followed by routed experts plus a shared expert, with
+                    Granite's scaled residuals
   audio (whisper)   enc-dec: bidirectional encoder blocks + causal decoder
                     blocks with cross-attention; LayerNorm + GELU
 
@@ -305,6 +308,59 @@ def ssm_block_decode(p, x, cache, pos, cfg: ArchConfig):
         p["mamba"], apply_norm(cfg, p["ln"], x), conv, state, cfg
     )
     return x + y, (conv, state)
+
+
+# ---------------------------------------------------------------------------
+# Hybrid MoE (granite-4.0-h): one layer body over T tokens against the cache
+# ---------------------------------------------------------------------------
+def attn_mixer_defs(cfg: ArchConfig) -> dict:
+    return {"ln": norm_defs(cfg), "attn": gqa_defs(cfg)}
+
+
+def moe_ffn_defs(cfg: ArchConfig) -> dict:
+    return {"ln": norm_defs(cfg), "moe": moe_defs(cfg)}
+
+
+def hybrid_moe_layer(p_mix, p_ffn, x, cache, pos, cfg: ArchConfig, *, attn: bool,
+                     verify: bool = False):
+    """One layer over T tokens at positions [pos, pos+T):
+
+        x = x + m_r * mixer(norm(x))
+        x = x + m_r * (routed(norm(x)) + shared(norm(x)))
+
+    The mixer is GQA attention (``attn``; ``cache`` is its (k, v) rows, no
+    position embedding, scores scaled by ``cfg.attention_multiplier``) or
+    Mamba-2 (``cache`` is its (conv, state) rows: one recurrence step at T=1,
+    the chunked scan above it, and with ``verify`` the state after every
+    position, for rollback).
+    """
+    m_r = cfg.residual_multiplier
+    h = _proj_norm(cfg, p_mix["ln"], x)
+    if attn:
+        if cfg.rope:
+            raise ValueError(f"{cfg.name}: hybrid_moe attention takes no position "
+                             "embedding; set rope=False")
+        k_cache, v_cache = cache
+        hd = cfg.resolved_head_dim
+        q_scale = cfg.attention_multiplier * hd ** 0.5 if cfg.attention_multiplier else 1.0
+        y, k_cache, v_cache = gqa_chunk_apply(p_mix["attn"], h, k_cache, v_cache, pos, cfg,
+                                              rope=False, q_scale=q_scale)
+        cache = (k_cache, v_cache)
+    else:
+        conv, state = cache
+        if verify:
+            mixer = ssm_mod.mamba_verify_apply
+        elif x.shape[1] == 1:
+            mixer = ssm_mod.mamba_decode_apply
+        else:
+            mixer = ssm_mod.mamba_chunk_apply
+        y, conv, state = mixer(p_mix["mamba"], h, conv, state, cfg)
+        cache = (conv, state)
+    x = x + y * m_r
+    h = _proj_norm(cfg, p_ffn["ln"], x)
+    with jax.named_scope("mlp"):
+        y, _ = moe_apply(p_ffn["moe"], h, cfg)
+    return x + y * m_r, cache
 
 
 def shared_attn_defs(cfg: ArchConfig) -> dict:

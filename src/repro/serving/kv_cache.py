@@ -7,6 +7,8 @@ caches — logical axes drive the mesh mapping):
   MLA (deepseek)  c: (L, B, S, r), krope: (L, B, S, rope_d)  — compressed
   SSM (mamba2)    conv: (L, B, W-1, d_inner+2N) bf16, state: (L, B, H, P, N) f32
   hybrid (zamba2) SSM caches + shared-attn k/v: (n_apps, B, S, KV, hd)
+  hybrid_moe      SSM caches over its Mamba-2 layers + k/v over its
+                  attention layers: (n_attn, B, S, KV, hd)
   audio (whisper) decoder self k/v + static cross k/v over encoder frames
 
 Sharding: batch → DP axes (when divisible), the KV *sequence* axis → "model"
@@ -50,24 +52,17 @@ def cache_defs(cfg: ArchConfig, *, batch: int, max_len: int) -> dict:
             "krope": ParamDef((l, b, s, m.qk_rope_head_dim), ("layers", "batch", "kv_seq", None), init="zeros", dtype=dt),
         }
     if f in ("ssm", "hybrid"):
-        sm = cfg.ssm
-        d_in = sm.d_inner(cfg.d_model)
-        nh = sm.num_heads(cfg.d_model)
-        out = {
-            "conv": ParamDef(
-                (l, b, sm.conv_width - 1, d_in + 2 * sm.state_size),
-                ("layers", "batch", None, None), init="zeros", dtype=dt,
-            ),
-            "state": ParamDef(
-                (l, b, nh, sm.head_dim, sm.state_size),
-                ("layers", "batch", "ssm_heads", None, None), init="zeros", dtype=jnp.float32,
-            ),
-        }
+        out = _recurrent(cfg, l, b, dt)
         if f == "hybrid":
             n_apps = math.ceil(cfg.num_layers / cfg.attn_every)
             out["shared_k"] = _kv(n_apps, b, s, cfg.num_kv_heads, hd, dt)
             out["shared_v"] = _kv(n_apps, b, s, cfg.num_kv_heads, hd, dt)
         return out
+    if f == "hybrid_moe":
+        n_attn = len(cfg.attention_layers)
+        return {**_recurrent(cfg, l - n_attn, b, dt),
+                "k": _kv(n_attn, b, s, cfg.num_kv_heads, hd, dt),
+                "v": _kv(n_attn, b, s, cfg.num_kv_heads, hd, dt)}
     if f == "audio":
         return {
             "k": _kv(l, b, s, cfg.num_kv_heads, hd, dt),
@@ -78,6 +73,32 @@ def cache_defs(cfg: ArchConfig, *, batch: int, max_len: int) -> dict:
     raise ValueError(f)
 
 
+def _recurrent(cfg: ArchConfig, layers: int, b: int, dt) -> dict:
+    """Mamba-2 state rows: conv tails (layers, B, W-1, d_inner+2N) and f32
+    states (layers, B, H, P, N)."""
+    sm = cfg.ssm
+    d_in = sm.d_inner(cfg.d_model)
+    nh = sm.num_heads(cfg.d_model)
+    return {
+        "conv": ParamDef(
+            (layers, b, sm.conv_width - 1, d_in + 2 * sm.state_size),
+            ("layers", "batch", None, None), init="zeros", dtype=dt,
+        ),
+        "state": ParamDef(
+            (layers, b, nh, sm.head_dim, sm.state_size),
+            ("layers", "batch", "ssm_heads", None, None), init="zeros", dtype=jnp.float32,
+        ),
+    }
+
+
+def has_recurrent_state(cfg: ArchConfig) -> bool:
+    """Whether the cache holds recurrent state rows (Mamba-2 conv tails and
+    states). Such state is per slot and not positional: it is never paged,
+    a prompt prefix cannot be shared through it, and a speculative verify
+    must roll it back to the last accepted token."""
+    return "state" in cache_defs(cfg, batch=1, max_len=1)
+
+
 def paged_keys(cfg: ArchConfig) -> tuple[str, ...]:
     """Cache keys whose SEQUENCE axis (axis 2) is paged by ``serving/pages``.
 
@@ -85,7 +106,7 @@ def paged_keys(cfg: ArchConfig) -> tuple[str, ...]:
     (recurrent, not positional) and audio cross K/V (fixed at encoder_seq).
     """
     f = cfg.family
-    if f in ("dense", "vlm", "audio") or (f == "moe" and cfg.mla is None):
+    if f in ("dense", "vlm", "audio", "hybrid_moe") or (f == "moe" and cfg.mla is None):
         return ("k", "v")
     if f == "moe":
         return ("c", "krope")

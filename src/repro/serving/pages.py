@@ -55,8 +55,8 @@ delta is computed. At most ``s0 - 1`` tokens are ever shared: the first
 emitted token comes from the prefill logits at the last prompt position,
 so at least one prompt token is always chunk-prefilled by the consumer.
 Sharing is causal-correct because a K/V row at position p depends only on
-tokens <= p; it is disabled for SSM/hybrid families, whose recurrent state
-is not positional.
+tokens <= p; it is disabled for every family whose cache holds recurrent
+state (``kv_cache.has_recurrent_state``), which is not positional.
 
 Speculative verify windows need no ``spec_slack`` spare rows here: the
 table always has at least one spare block past ``max_len``, and tail
@@ -112,8 +112,8 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.models.params import init_params
-from repro.serving.kv_cache import (cache_defs, dequantize_kv, page_defs,
-                                    paged_keys, quantize_kv)
+from repro.serving.kv_cache import (cache_defs, dequantize_kv, has_recurrent_state,
+                                    page_defs, paged_keys, quantize_kv)
 from repro.serving.slots import SlotInfo, SlotPool
 
 SCRATCH = 0  # reserved physical page: unmapped / redirected writes land here
@@ -216,7 +216,7 @@ class PagedSlotPool(SlotPool):
         # registry digests prompt TOKENS only and early cache rows also
         # depend on per-request frontend embeddings
         self.share_prefix = (bool(share_prefix)
-                             and cfg.family not in ("ssm", "hybrid")
+                             and not has_recurrent_state(cfg)
                              and cfg.frontend is None)
         if num_pages is None:
             # parity default: same worst case as the contiguous pool, plus

@@ -47,15 +47,15 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.models.params import init_params
-from repro.serving.kv_cache import cache_defs
+from repro.serving.kv_cache import cache_defs, paged_keys
 
 
 def grow_cache(cfg: ArchConfig, cache: dict, max_len: int) -> dict:
     """Pad prefill-produced seq-dim caches out to ``max_len`` capacity.
 
-    SSM conv/state caches are O(1) in sequence — nothing to grow; the
-    hybrid family grows only its shared-attention K/V, audio only its
-    decoder self-attention K/V (cross K/V is fixed at encoder_seq).
+    Only the sequence-dim leaves grow (``kv_cache.paged_keys``, axis 2):
+    recurrent state rows are O(1) in sequence and audio cross K/V is fixed
+    at encoder_seq.
     """
 
     def grow(x, axis):
@@ -66,18 +66,7 @@ def grow_cache(cfg: ArchConfig, cache: dict, max_len: int) -> dict:
         widths[axis] = (0, pad)
         return jnp.pad(x, widths)
 
-    f = cfg.family
-    if f in ("dense", "vlm", "audio") or (f == "moe" and cfg.mla is None):
-        cache = dict(cache, k=grow(cache["k"], 2), v=grow(cache["v"], 2))
-    elif f == "moe":
-        cache = dict(cache, c=grow(cache["c"], 2), krope=grow(cache["krope"], 2))
-    elif f == "hybrid":
-        cache = dict(
-            cache,
-            shared_k=grow(cache["shared_k"], 2),
-            shared_v=grow(cache["shared_v"], 2),
-        )
-    return cache  # ssm caches are O(1) — nothing to grow
+    return dict(cache, **{k: grow(cache[k], 2) for k in paged_keys(cfg)})
 
 
 @dataclasses.dataclass

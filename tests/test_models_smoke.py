@@ -27,7 +27,10 @@ def _batch(cfg, key):
 
 
 def test_all_ten_archs_registered():
-    assert len(ARCHS) == 10
+    assert set(ARCHS) == {
+        "deepseek-v3-671b", "granite-3-8b", "granite-34b", "granite-4.0-h-small",
+        "granite-moe-3b-a800m", "internvl2-76b", "mamba2-780m", "qwen1.5-110b",
+        "starcoder2-15b", "whisper-tiny", "zamba2-7b"}
     for a in ARCHS:
         full = get_config(a)
         red = get_reduced_config(a)
@@ -162,6 +165,7 @@ def test_param_counts_in_published_ballpark():
         "zamba2-7b": (6e9, 8.5e9),
         "whisper-tiny": (20e6, 60e6),
         "granite-moe-3b-a800m": (2.5e9, 4e9),
+        "granite-4.0-h-small": (30e9, 34e9),  # "32B-A9B"
     }
     for a, (lo, hi) in expect.items():
         n = get_config(a).param_count()
@@ -169,7 +173,9 @@ def test_param_counts_in_published_ballpark():
 
 
 def test_moe_active_params():
-    cfg = get_config("granite-moe-3b-a800m")
-    active = cfg.active_param_count()
-    assert active < cfg.param_count()
-    assert 0.5e9 <= active <= 1.5e9, active / 1e9  # "a800m" ≈ 0.8B active
+    for arch, lo, hi in [("granite-moe-3b-a800m", 0.5e9, 1.5e9),  # "a800m" ≈ 0.8B active
+                         ("granite-4.0-h-small", 8e9, 10e9)]:     # "A9B": top-10 of 72
+        cfg = get_config(arch)
+        active = cfg.active_param_count()
+        assert active < cfg.param_count()
+        assert lo <= active <= hi, (arch, active / 1e9)

@@ -17,7 +17,7 @@ from repro.serving.pages import PagedSlotPool
 from repro.serving.scheduler import ContinuousBatchingScheduler, FixedCalibration
 
 FAMILY_ARCHS = ("granite-3-8b", "deepseek-v3-671b", "mamba2-780m",
-                "zamba2-7b", "whisper-tiny")
+                "zamba2-7b", "whisper-tiny", "granite-4.0-h-small")
 
 
 def _engines_f32(arch, *, max_batch=2, max_len=32, page_size=4, slack=0,
@@ -71,7 +71,7 @@ def test_paged_token_identical_every_family(arch):
     _drained(sched)
 
 
-@pytest.mark.parametrize("arch", ("granite-3-8b", "zamba2-7b"))
+@pytest.mark.parametrize("arch", ("granite-3-8b", "zamba2-7b", "granite-4.0-h-small"))
 def test_paged_chunked_and_speculative_identical(arch):
     """Chunked admission activates out of a contiguous group cache into
     pages; speculative verify windows write tail blocks allocated on demand

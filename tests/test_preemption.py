@@ -28,7 +28,7 @@ from repro.serving.scheduler import (
 )
 
 FAMILY_ARCHS = ("granite-3-8b", "deepseek-v3-671b", "mamba2-780m",
-                "zamba2-7b", "whisper-tiny")
+                "zamba2-7b", "whisper-tiny", "granite-4.0-h-small")
 
 CAL = FixedCalibration(step_s=0.004, prefill_base_s=0.001,
                        prefill_per_tok_s=0.001, verify_per_tok_s=0.0001)

@@ -30,7 +30,7 @@ from repro.serving.scheduler import (
 # one representative per architecture family (dense / MLA-MoE / SSM / hybrid
 # / audio) — the masked decode path must hold for every cache layout
 FAMILY_ARCHS = ("granite-3-8b", "deepseek-v3-671b", "mamba2-780m",
-                "zamba2-7b", "whisper-tiny")
+                "zamba2-7b", "whisper-tiny", "granite-4.0-h-small")
 
 
 def _engine(arch, max_batch=2, max_len=32):

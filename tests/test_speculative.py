@@ -15,7 +15,7 @@ from repro.serving.load import Request, bursty_stream, poisson_stream
 from repro.serving.scheduler import ContinuousBatchingScheduler, FixedCalibration
 
 FAMILY_ARCHS = ("granite-3-8b", "deepseek-v3-671b", "mamba2-780m",
-                "zamba2-7b", "whisper-tiny")
+                "zamba2-7b", "whisper-tiny", "granite-4.0-h-small")
 
 
 def _engine_f32(arch, max_batch=2, max_len=32, slack=4):
