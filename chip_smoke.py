@@ -16,7 +16,9 @@ before the last line is printed:
             ``kernels/ref.py`` oracle: the LSTM kernels at the paper's
             workload and at B=40, S=28, D=H=256; ``int8_matmul`` at one
             granite-3-8b projection; ``flash_attention`` at granite-3-8b's
-            heads over 2048 positions.
+            heads over 2048 positions; ``paged_decode_attention`` over
+            16 slots of granite-3-8b's K/V pages at mixed positions up to
+            4096, vmapped over the slots as the decode step calls it.
   serve     granite-3-8b at every published width, depth cut 40 -> 16 to
             fit one chip, bf16 seeded random weights, built by the serving
             launcher's own ``build_server`` (InferenceEngine -> PagedSlotPool
@@ -32,6 +34,7 @@ smoke run, not a benchmark: its times include compilation and set-up.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import json
 import os
@@ -53,6 +56,7 @@ from repro.kernels import runtime  # noqa: E402
 from repro.kernels.runtime import enable_compile_cache  # noqa: E402
 from repro.kernels.flash_attention import flash_attention  # noqa: E402
 from repro.kernels.int8_matmul import int8_matmul  # noqa: E402
+from repro.kernels.paged_attention import paged_decode_attention  # noqa: E402
 from repro.kernels.lstm_quant import quantize_lstm_weights  # noqa: E402
 from repro.kernels.lstm_seq import (  # noqa: E402
     lstm_seq_fused, lstm_seq_fused_quantized, lstm_stack_fused)
@@ -72,6 +76,7 @@ PAPER_LSTM = (64, 28, 6, 20)     # batch, seq, d_in, hidden (core/fpga.py)
 WIDE_LSTM = (40, 28, 256, 256)   # the larger committed LSTM shape
 GRANITE_PROJ = (256, 4096, 12800)        # m, k, n: an MLP up-projection
 GRANITE_ATTN = (1, 32, 8, 2048, 128)     # batch, heads, kv heads, seq, dim
+GRANITE_PAGED = (16, 32, 8, 128, 16, 4096)  # slots, heads, kv heads, dim, page, max_len
 
 # Tolerances, each with its reason -------------------------------------------
 # LSTM outputs lie in (-1, 1). The kernels set no matmul precision, so Mosaic
@@ -86,6 +91,12 @@ INT8_RTOL = 1e-6
 # f32 online-softmax reassociation can move a value across one rounding
 # boundary, so allow two spacings at the largest output.
 FLASH_RTOL = 2.0 ** -6
+# Paged decode attention returns bf16 too, and contracts P with V in bf16
+# (2^-9 relative per weight, an f32 sum) where the oracle keeps P in f32:
+# that moves an output by well under one spacing, so the same two spacings
+# at the largest output hold; a wrong page, head or position moves it by
+# its own scale.
+PAGED_RTOL = 2.0 ** -6
 # The served model runs bf16 weights, activations and KV cache (relative
 # rounding 2^-9 per operation). With well-conditioned weights (see
 # ``_d_model_fan_in``) that moves logits by about 1% of their largest value;
@@ -181,6 +192,7 @@ def _oracle(fn, *args):
 
 def kernel_phase(*, lstm_shapes=(PAPER_LSTM, WIDE_LSTM),
                  matmul_shape=GRANITE_PROJ, attention_shape=GRANITE_ATTN,
+                 paged_shape=GRANITE_PAGED,
                  interpret: bool = False) -> dict[str, float]:
     """Each Pallas kernel against its oracle; returns max errors by name."""
     errs = {}
@@ -233,7 +245,50 @@ def kernel_phase(*, lstm_shapes=(PAPER_LSTM, WIDE_LSTM),
         _oracle(lambda *a: ref.flash_attention_ref(*a, causal=True),
                 q, kt, vt))
     _report(name, errs[name], FLASH_RTOL * scale)
+
+    errs.update(_paged_attention_check(key, paged_shape, interpret))
     return errs
+
+
+def _paged_attention_check(key, shape, interpret: bool) -> dict[str, float]:
+    """``paged_decode_attention`` vmapped over slots, as the decode step
+    calls it, against its oracle: a two-layer pool, each slot's pages drawn
+    at random, positions spread up to ``max_len - 1``, one slot inactive on
+    the scratch page, and every row past a slot's position NaN."""
+    slots, h, kvh, d, page, max_len = shape
+    mb = max_len // page
+    n_pages = slots * mb + 1
+    ks = jax.random.split(key, 5)
+    q = jax.random.normal(ks[0], (slots, h, d), jnp.bfloat16)
+    k_new = jax.random.normal(ks[1], (slots, kvh, d), jnp.bfloat16)
+    v_new = jax.random.normal(ks[2], (slots, kvh, d), jnp.bfloat16)
+    rng = np.random.default_rng(SEED)
+    pos = rng.integers(0, max_len, slots).astype(np.int32)
+    pos[:4] = (0, page - 1, page, max_len - 1)
+    table = 1 + rng.permutation(n_pages - 1)
+    table = table.reshape(slots, mb).astype(np.int32)
+    pos[-1], table[-1] = 0, 0  # an inactive slot: every block on scratch page 0
+    live = (np.arange(mb * page)[None, :] < pos[:, None]).reshape(slots, mb, page)
+    rows = np.zeros((2, n_pages, page), bool)
+    rows[:, table, :] = live[None]
+    dead = jnp.asarray(~rows)[..., None, None]
+    k_pages = jnp.where(dead, jnp.nan, jax.random.normal(
+        ks[3], (2, n_pages, page, kvh, d), jnp.bfloat16))
+    v_pages = jnp.where(dead, jnp.nan, jax.random.normal(
+        ks[4], (2, n_pages, page, kvh, d), jnp.bfloat16))
+    layer = jnp.int32(1)
+    args = (q, k_new, v_new, k_pages, v_pages, jnp.asarray(table), jnp.asarray(pos))
+    # the pool is an argument, shared by the slots, never a traced constant
+    per_slot = lambda fn: jax.vmap(  # noqa: E731
+        lambda q, kn, vn, kp, vp, t, p: fn(q, kn, vn, kp, vp, t, p, layer),
+        in_axes=(0, 0, 0, None, None, 0, 0))
+    got = jax.jit(per_slot(functools.partial(
+        paged_decode_attention, interpret=interpret)))(*args)
+    want = _oracle(per_slot(ref.paged_decode_attention_ref), *args)
+    name = f"paged_decode_attention slots={slots} H={h} KV={kvh} D={d} page={page}"
+    err, scale = _max_err(got, want)
+    _report(name, err, PAGED_RTOL * scale)
+    return {name: err}
 
 
 # ---------------------------------------------------------------------------

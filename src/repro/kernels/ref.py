@@ -44,6 +44,34 @@ def flash_attention_ref(q, k, v, *, causal: bool):
 
 
 # ---------------------------------------------------------------------------
+# Paged decode attention (one new token per slot, GQA, pages in place)
+# ---------------------------------------------------------------------------
+def paged_decode_attention_ref(q, k_new, v_new, k_pages, v_pages, table, pos, layer):
+    """One slot: q (H, D) at position ``pos``; k_new/v_new (KV, D), the new
+    token's own k and v; pages (lead, num_pages, page, KV, D); table
+    (max_blocks,); layer (). Returns (H, D) in q's dtype.
+
+    Gathers the slot's row of layer ``layer`` through its table, puts the new
+    token at ``pos`` and attends over positions ``0..pos``: query head ``h``
+    reads KV head ``h // G``, scores in f32 from K as stored over
+    ``sqrt(D)``, softmax in f32, P in f32 against V. Rows past ``pos`` are
+    masked from the scores and zeroed in V, so whatever they hold (stale,
+    scratch, NaN) adds nothing."""
+    h, d = q.shape
+    kv = k_pages.shape[3]
+    k = k_pages[layer, table].reshape(-1, kv, d)  # (max_blocks * page, KV, D)
+    v = v_pages[layer, table].reshape(-1, kv, d)
+    at = jnp.arange(k.shape[0])[:, None, None]
+    k = jnp.where(at == pos, k_new.astype(k.dtype), k)
+    v = jnp.where(at <= pos, jnp.where(at == pos, v_new.astype(v.dtype), v), 0)
+    qg = q.reshape(kv, h // kv, d)
+    s = jnp.einsum("kgd,skd->kgs", qg, k, preferred_element_type=jnp.float32)
+    s = jnp.where(at[:, 0, 0] <= pos, s / jnp.sqrt(d), NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("kgs,skd->kgd", p, v).reshape(h, d).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
 # Fused LSTM cell
 # ---------------------------------------------------------------------------
 def lstm_cell_ref(x, h, c, w, u, b, *, impl: str = "exact"):

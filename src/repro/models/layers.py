@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.models.paged import PagedRows
 from repro.models.params import ParamDef
 from repro.models.quant import qeinsum
 from repro.sharding.rules import constrain
@@ -324,24 +325,33 @@ def gqa_chunk_apply(params, x, cache_k, cache_v, pos, cfg: ArchConfig, *, rope: 
     """Chunked-prefill attention: T prompt tokens appended at ``pos``.
 
     x: (B,T,D). Returns (out, k_cache, v_cache) with the chunk's K/V written
-    into the cache span [pos, pos+T). ``q_scale`` multiplies the queries
-    (in f32, before they are rounded back), so the scores are scaled by
-    ``q_scale / sqrt(head_dim)``."""
+    into the cache span [pos, pos+T); given in-place ``PagedRows`` (one
+    decode token, ``models.paged``) it reads the pages and returns the new
+    k and v rows instead, as ``gqa_decode_apply`` does. ``q_scale``
+    multiplies the queries (in f32, before they are rounded back), so the
+    scores are scaled by ``q_scale / sqrt(head_dim)``."""
     positions = (pos + jnp.arange(x.shape[1]))[None, :]
     q, k_new, v_new = gqa_project_qkv(params, x, cfg, positions, rope=rope)
     if q_scale != 1.0:
         with jax.named_scope("proj"):
             q = (q.astype(jnp.float32) * q_scale).astype(q.dtype)
-    k_cache = write_cache_span(cache_k, k_new, pos)
-    v_cache = write_cache_span(cache_v, v_new, pos)
-    out = attention_chunk(q, k_cache, v_cache, pos)
+    if isinstance(cache_k, PagedRows):  # one decode token over pages in place
+        out = cache_k.attend(q, k_new, v_new, cache_v, pos)
+        k_cache, v_cache = k_new, v_new
+    else:
+        k_cache = write_cache_span(cache_k, k_new, pos)
+        v_cache = write_cache_span(cache_v, v_new, pos)
+        out = attention_chunk(q, k_cache, v_cache, pos)
     with jax.named_scope("proj"):
         out = qeinsum("bshe,hed->bsd", out, params["wo"])
     return out, k_cache, v_cache
 
 
 def gqa_decode_apply(params, x, cache_k, cache_v, pos, cfg: ArchConfig, *, rope: bool = True):
-    """One-token decode. x: (B,1,D). Returns (out, new_k_slice, new_v_slice).
+    """One-token decode. x: (B,1,D). Returns (out, k_cache, v_cache), the
+    caches updated at ``pos``; for in-place ``PagedRows`` caches
+    (``models.paged``) the pages are read where they lie and the new k and v
+    rows, (B,1,KV,D), come back in the caches' place.
 
     Flash-decoding sharding: the KV cache is SEQUENCE-sharded over "model"
     while q comes out of the projection heads-sharded over the same axis —
@@ -357,9 +367,13 @@ def gqa_decode_apply(params, x, cache_k, cache_v, pos, cfg: ArchConfig, *, rope:
     q = constrain(q, ("batch", None, None, None))
     k_new = constrain(k_new, ("batch", None, None, None))
     v_new = constrain(v_new, ("batch", None, None, None))
-    k_cache = write_cache(cache_k, k_new, pos, cfg)
-    v_cache = write_cache(cache_v, v_new, pos, cfg)
-    out = attention_decode(q, k_cache, v_cache, pos)
+    if isinstance(cache_k, PagedRows):
+        out = cache_k.attend(q, k_new, v_new, cache_v, pos)
+        k_cache, v_cache = k_new, v_new
+    else:
+        k_cache = write_cache(cache_k, k_new, pos, cfg)
+        v_cache = write_cache(cache_v, v_new, pos, cfg)
+        out = attention_decode(q, k_cache, v_cache, pos)
     with jax.named_scope("proj"):
         out = qeinsum("bshe,hed->bsd", out, params["wo"])
     return out, k_cache, v_cache

@@ -41,6 +41,7 @@ from repro.configs.base import ArchConfig
 from repro.models import transformer as T
 from repro.models import ssm as ssm_mod
 from repro.models.layers import embed_defs, embed_apply, unembed_apply
+from repro.models.paged import PagedRows, cache_view, cache_written
 from repro.models.params import ParamDef, init_params, stacked
 from repro.models.quant import qeinsum
 from repro.sharding.rules import constrain
@@ -152,19 +153,23 @@ def run_stack_prefill(stack, x, body, cfg: ArchConfig):
 
 def run_stack_decode(stack, caches, x, body, pos, cfg: ArchConfig):
     """body(p, x, cache, pos) -> (x, cache). caches: stacked tuple whose
-    elements may be ``PagedRows``: those are gathered one layer at a time
-    and come back as the layer's written blocks."""
+    elements may be ``PagedRows`` (``models.paged``): the scan carries such a
+    view's layer index, never its pages, and each layer's view comes back as
+    what the layer writes (its new row, or its written blocks)."""
+    per_layer = tuple(c.layer if isinstance(c, PagedRows) else c for c in caches)
 
     def f(x, inp):
-        p, cache = inp
-        x, new = body(p, x, tuple(map(_cache_view, cache)), pos)
-        return x, tuple(_cache_written(n, c, pos) for n, c in zip(new, cache))
+        p, sliced = inp
+        cache = tuple(c.at(s) if isinstance(c, PagedRows) else s
+                      for c, s in zip(caches, sliced))
+        x, new = body(p, x, tuple(map(cache_view, cache)), pos)
+        return x, tuple(cache_written(n, c, pos) for n, c in zip(new, cache))
 
     if cfg.scan_layers:
-        return jax.lax.scan(f, x, (stack, caches))
+        return jax.lax.scan(f, x, (stack, per_layer))
     outs = []
     for i in range(_stack_len(stack)):
-        x, c = f(x, (_layer(stack, i), _layer(caches, i)))
+        x, c = f(x, (_layer(stack, i), _layer(per_layer, i)))
         outs.append(c)
     return x, jax.tree.map(lambda *ts: jnp.stack(ts), *outs)
 
@@ -196,7 +201,7 @@ def _stack_slice(stack, start, length):
 def _hybrid_moe_stack(params, cache, tokens, pos, cfg: ArchConfig, *, verify=False):
     """The hybrid_moe family's one body: T tokens at positions [pos, pos+T)
     against a cache of Mamba-2 rows (``conv``, ``state``) and attention rows
-    (``k``, ``v``; arrays, or ``PagedRows`` gathered a layer at a time).
+    (``k``, ``v``; arrays, or ``PagedRows``).
     ``forward`` and ``prefill`` run it over a zero cache from position 0,
     ``decode_step`` at T=1, ``prefill_chunk`` and ``decode_verify`` as they
     come. Returns (hidden states before the final norm, cache)."""
@@ -212,10 +217,10 @@ def _hybrid_moe_stack(params, cache, tokens, pos, cfg: ArchConfig, *, verify=Fal
         if i in cfg.attn_layers:
             kc, vc = cache["k"][i_attn], cache["v"][i_attn]
             x, (k, v) = T.hybrid_moe_layer(
-                _layer(blocks["attn"], i_attn), ffn, x, (_cache_view(kc), _cache_view(vc)),
+                _layer(blocks["attn"], i_attn), ffn, x, (cache_view(kc), cache_view(vc)),
                 pos, cfg, attn=True)
-            new["k"].append(_cache_written(k, kc, pos))
-            new["v"].append(_cache_written(v, vc, pos))
+            new["k"].append(cache_written(k, kc, pos))
+            new["v"].append(cache_written(v, vc, pos))
             i_attn += 1
         else:
             x, (conv, state) = T.hybrid_moe_layer(
@@ -506,11 +511,11 @@ def decode_step(params, cache, token, pos, cfg: ArchConfig):
         for i, (start, length) in enumerate(_hybrid_segments(cfg)):
             ck, cv = cache["shared_k"][i], cache["shared_v"][i]
             x, sk, sv = T.shared_attn_decode(
-                params["shared"], x, x0, _cache_view(ck), _cache_view(cv),
+                params["shared"], x, x0, cache_view(ck), cache_view(cv),
                 pos, cfg,
             )
-            sks.append(_cache_written(sk, ck, pos))
-            svs.append(_cache_written(sv, cv, pos))
+            sks.append(cache_written(sk, ck, pos))
+            svs.append(cache_written(sv, cv, pos))
             seg = _stack_slice(params["blocks"], start, length)
             segc = (
                 jax.lax.slice_in_dim(cache["conv"], start, start + length, axis=0),
@@ -618,11 +623,11 @@ def _chunk_forward(params, cache, tokens, pos, cfg: ArchConfig, frontend_embeds=
         for i, (start, length) in enumerate(_hybrid_segments(cfg)):
             ck, cv = cache["shared_k"][i], cache["shared_v"][i]
             x, sk, sv = T.shared_attn_chunk(
-                params["shared"], x, x0, _cache_view(ck), _cache_view(cv),
+                params["shared"], x, x0, cache_view(ck), cache_view(cv),
                 pos, cfg,
             )
-            sks.append(_cache_written(sk, ck, pos))
-            svs.append(_cache_written(sv, cv, pos))
+            sks.append(cache_written(sk, ck, pos))
+            svs.append(cache_written(sv, cv, pos))
             seg = _stack_slice(params["blocks"], start, length)
             segc = (
                 jax.lax.slice_in_dim(cache["conv"], start, start + length, axis=0),
@@ -725,96 +730,3 @@ def _mask_pad_logits(logits, cfg: ArchConfig):
     if cfg.logits_scaling != 1.0:
         logits = logits.astype(jnp.float32) / cfg.logits_scaling
     return logits
-
-
-# ---------------------------------------------------------------------------
-# Paged-cache bridges (serving/pages.py)
-# ---------------------------------------------------------------------------
-# The decode/verify bodies above are layout-agnostic: they see a per-slot
-# contiguous cache row and write positions [pos, pos+T) through the strict
-# positional masks in models/layers.py. The paged serving path reuses them
-# unchanged by (a) gathering a slot's pages into a VIRTUAL contiguous row
-# through its page-table row, and (b) extracting the written blocks back out
-# for a scatter by page id. Rows gathered from unmapped blocks (the scratch
-# page) are garbage, but the positional masks select NEG_INF for every
-# position > pos before the softmax, so they are exactly inert in f32.
-#
-# The gather happens inside the layer loop, one layer at a time: a whole-
-# stack virtual row per slot would hold max_batch x layers x max_len rows at
-# once (8 GiB of temporaries for granite-3-8b at 16 layers, 16 slots and
-# 4k rows, which does not fit a 16 GiB chip next to the weights and pages).
-
-
-@jax.tree_util.register_pytree_node_class
-class PagedRows:
-    """One slot's view of a paged cache leaf, for the decode/verify bodies.
-
-    pages: (lead, num_pages, page, *tail) physical pages (int8 payloads
-    under ``kv_quant``, with their f32 ``scales`` pages; else None);
-    table: (lead, max_blocks) — the slot's page-table row repeated along
-    ``lead``, so a layer scan slices it together with the pages.
-    ``n_blocks`` whole blocks from the block holding ``pos`` are what the
-    step writes back (1 for decode, the verify window's span for verify).
-    """
-
-    def __init__(self, pages, scales, table, *, n_blocks: int, page: int):
-        self.pages, self.scales, self.table = pages, scales, table
-        self.n_blocks, self.page = n_blocks, page
-
-    def tree_flatten(self):
-        return (self.pages, self.scales, self.table), (self.n_blocks, self.page)
-
-    @classmethod
-    def tree_unflatten(cls, aux, children):
-        return cls(*children, n_blocks=aux[0], page=aux[1])
-
-    def __getitem__(self, idx):
-        """Slice the ``lead`` axis (deepseek's dense/MoE split, hybrid's
-        per-application shared attention)."""
-        return jax.tree.map(lambda t: t[idx], self)
-
-    def gather(self):
-        """One layer's virtual contiguous row: (1, max_blocks * page, *tail)."""
-        with jax.named_scope("kv_pages"):
-            g = jnp.take(self.pages, self.table, axis=0)  # (max_blocks, page, *tail)
-            if self.scales is not None:
-                from repro.serving.kv_cache import dequantize_kv
-
-                g = dequantize_kv(g, jnp.take(self.scales, self.table, axis=0))
-            return g.reshape(1, -1, *g.shape[2:])
-
-    def written(self, row, pos):
-        """The step's written blocks of an updated row: (n_blocks, page, *tail)."""
-        with jax.named_scope("kv_pages"):
-            return paged_written_blocks(row, pos // self.page, self.n_blocks,
-                                        self.page)[:, 0]
-
-
-def _cache_view(c):
-    return c.gather() if isinstance(c, PagedRows) else c
-
-
-def _cache_written(new, c, pos):
-    return c.written(new, pos) if isinstance(c, PagedRows) else new
-
-
-def paged_written_blocks(row, first_blk, n_blocks, page_size):
-    """Extract ``n_blocks`` whole blocks of a virtual cache row starting at
-    traced block index ``first_blk``.
-
-    row: (lead, S, *tail) → (n_blocks, lead, page_size, *tail). The row is
-    padded by the slice width first so ``dynamic_slice`` never clamps the
-    start (a clamp would silently misalign block boundaries)."""
-    span = n_blocks * page_size
-    widths = [(0, 0), (0, span)] + [(0, 0)] * (row.ndim - 2)
-    padded = jnp.pad(row, widths)
-    w = jax.lax.dynamic_slice_in_dim(padded, first_blk * page_size, span, axis=1)
-    w = w.reshape(w.shape[0], n_blocks, page_size, *w.shape[2:])
-    return jnp.moveaxis(w, 1, 0)
-
-
-def verify_block_span(window: int, page_size: int) -> int:
-    """Worst-case whole blocks a verify window of ``window`` tokens can touch
-    (window starting at the last row of a block spills ceil((window-1)/page)
-    more blocks)."""
-    return 1 + (window + page_size - 2) // page_size

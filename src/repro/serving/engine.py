@@ -36,11 +36,10 @@ from repro.models.model import (
     decode_verify,
     encoder_cross_cache,
     init_model,
-    PagedRows,
     prefill,
     prefill_chunk,
-    verify_block_span,
 )
+from repro.models.paged import PagedRows, verify_block_span
 from repro.models.params import init_params
 from repro.serving.faults import FaultProfile
 from repro.serving.kv_cache import cache_defs, paged_keys, quantize_kv
@@ -263,9 +262,11 @@ class InferenceEngine:
         the device state.
 
         Profiler spans: ``engine.decode.prepare`` (page-table writes and the
-        host arrays), ``engine.decode.dispatch`` (uploads and the launch)
-        and ``engine.decode.readback`` (waiting for the device, the copies
-        and the guard's check).
+        host arrays), ``engine.decode.dispatch`` (uploads and the launch;
+        where the step reads K/V pages in place it carries
+        ``kv_pages_read``, the sum over decoding slots of ``pos // page +
+        1``) and ``engine.decode.readback`` (waiting for the device, the
+        copies and the guard's check).
         """
         paged = isinstance(pool, PagedSlotPool)
         with TraceAnnotation("engine.decode.prepare"):
@@ -277,9 +278,13 @@ class InferenceEngine:
                     p = pool.slots[s].pos
                     pool.ensure_writable(s, p, p + 1)
             host = (pool.tok, pool.positions(), pool.decode_mask())
+            span = {}
             if paged:
                 host += (pool.table,)
-        with TraceAnnotation("engine.decode.dispatch"):
+                if any(self._reads_in_place(pool.cache[k]) for k in paged_keys(self.cfg)):
+                    # pages the decode attention reads in place this step
+                    span["kv_pages_read"] = int((host[1][host[2]] // pool.page + 1).sum())
+        with TraceAnnotation("engine.decode.dispatch", **span):
             step = self._paged_decode if paged else self._masked_decode
             (nxt, fin), pool.cache = step(self.params, pool.cache,
                                           *map(jnp.asarray, host))
@@ -313,36 +318,36 @@ class InferenceEngine:
         return jax.vmap(one, in_axes=(1, 0, 0), out_axes=((0, 0), 1))(
             cache, tok, pos)
 
-    def _paged_rows(self, cache, table_row, n_blocks):
-        """One slot's ``PagedRows`` view of each paged cache leaf (the model
-        gathers them a layer at a time)."""
-        pkeys = paged_keys(self.cfg)
+    def _paged_rows(self, cache, table_row, n_blocks, *, decode: bool):
+        """One slot's ``PagedRows`` view of each paged cache leaf. The decode
+        step reads GQA K/V pages (tail ``(KV, D)``, not int8) in place; every
+        other leaf, and the verify window, gathers a layer at a time."""
         quant = self.sc.kv_quant
-        views = {}
-        for k in pkeys:
-            pages = cache[k]
-            views[k] = PagedRows(
-                pages, cache[f"{k}_scale"] if quant else None,
-                jnp.broadcast_to(table_row, (pages.shape[0], *table_row.shape)),
-                n_blocks=n_blocks, page=self.sc.page_size)
-        return views
+        return {k: PagedRows(
+            cache[k], cache[f"{k}_scale"] if quant else None, table_row,
+            jnp.arange(cache[k].shape[0]), n_blocks=n_blocks, page=self.sc.page_size,
+            in_place=decode and self._reads_in_place(cache[k]))
+            for k in paged_keys(self.cfg)}
+
+    def _reads_in_place(self, pages) -> bool:
+        return pages.ndim == 5 and self.sc.kv_quant is None
 
     def _paged_decode_impl(self, params, cache, tok, pos, active, table):
-        """Paged twin of ``_masked_decode_impl``: each slot's cache row is
-        gathered through its table row, one layer at a time inside the
-        model's layer loop (``PagedRows``), the identical per-slot decode
-        body runs, and the written block is scattered back by page id.
+        """Paged twin of ``_masked_decode_impl``: the identical per-slot
+        decode body runs over ``PagedRows`` views of the pool
+        (``models.paged``), and what each layer writes goes back by page id.
 
-        Rows gathered from unmapped blocks (scratch) are garbage, but every
-        position > pos is masked to NEG_INF before the softmax, so they are
-        exactly inert — the paged step is token-for-token the contiguous
-        step in f32. Inactive slots' writes are redirected to page 0.
-
-        Under ``kv_quant`` the gather also dequantizes (payload pages times
-        their "{key}_scale" pages) and the written block is re-quantized
-        before the scatter; re-quantizing the block's untouched rows is
-        idempotent, so only the freshly written position changes."""
-        cfg = self.cfg
+        GQA K/V pages are read in place by the decode attention kernel, up
+        to each slot's position, with the new token's k and v merged there;
+        each layer returns only that new row, and after the step each row is
+        written into its page at ``(table[pos // page], pos % page)``. Other
+        paged leaves (MLA's latent rows, int8 pages) gather each slot's row
+        a layer at a time and scatter back the written block; under
+        ``kv_quant`` the gather dequantizes and the written block is
+        re-quantized (idempotent on its untouched rows). Rows past ``pos``
+        are never read unmasked, so the paged step is token-for-token the
+        contiguous step. Inactive slots' writes are redirected to page 0."""
+        cfg, page = self.cfg, self.sc.page_size
         pkeys = paged_keys(cfg)
         quant = self.sc.kv_quant
         skeys = tuple(f"{k}_scale" for k in pkeys) if quant else ()
@@ -352,18 +357,18 @@ class InferenceEngine:
 
         def one(rest_b, tok_b, pos_b, tab_b, act_b):
             c1 = {**jax.tree.map(lambda t: jnp.expand_dims(t, 1), rest_b),
-                  **self._paged_rows(paged, tab_b, 1)}
+                  **self._paged_rows(paged, tab_b, 1, decode=True)}
             logits, c1 = decode_step(params, c1, tok_b[None, None], pos_b, cfg)
             nxt, fin = _greedy(logits[0, : cfg.vocab_size])
             with jax.named_scope("kv_pages"):
                 written = {}
                 for k in pkeys:
-                    w = c1[k][:, 0]  # (lead, page, *tail): the one written block
+                    w = c1[k][:, 0]  # (lead, *tail) new row, or (lead, page, *tail) block
                     if quant:
                         written[k], written[f"{k}_scale"] = quantize_kv(w)
                     else:
                         written[k] = w
-                pid = jnp.where(act_b, jnp.take(tab_b, pos_b // self.sc.page_size), 0)
+                pid = jnp.where(act_b, jnp.take(tab_b, pos_b // page), 0)
             return (nxt, fin, written, pid), {
                 k: jnp.squeeze(c1[k], 1) for k in rest}
 
@@ -372,7 +377,11 @@ class InferenceEngine:
             rest, tok, pos, table, active)
         with jax.named_scope("kv_pages"):
             for k in paged:
-                paged[k] = paged[k].at[:, pids].set(jnp.moveaxis(written[k], 0, 1))
+                w = jnp.moveaxis(written[k], 0, 1).astype(paged[k].dtype)
+                if self._reads_in_place(paged[k]):  # one row per slot and layer
+                    paged[k] = paged[k].at[:, pids, pos % page].set(w)
+                else:
+                    paged[k] = paged[k].at[:, pids].set(w)
         return (nxt, fin), {**rest1, **paged}
 
     # -- fault injection ------------------------------------------------------
@@ -543,7 +552,7 @@ class InferenceEngine:
 
         def one(rest_b, toks_b, pos_b, tab_b, act_b):
             c1 = {**jax.tree.map(lambda t: jnp.expand_dims(t, 1), rest_b),
-                  **self._paged_rows(paged, tab_b, nw)}
+                  **self._paged_rows(paged, tab_b, nw, decode=False)}
             logits, c1 = decode_verify(params, c1, toks_b[None, :], pos_b, cfg)
             g, fin = _greedy(logits[0, :, : cfg.vocab_size])
             ok = jnp.cumprod((toks_b[1:] == g[:-1]).astype(jnp.int32))

@@ -15,12 +15,14 @@ sequence positions are split into logical blocks of ``page_size`` rows:
   physical row of leaf = pages[:, table[slot, p // page_size], p % page_size]
 
 ``table`` is a dense int32 ``(max_batch, max_blocks)`` array passed INTO the
-decode/verify jits, so the paged paths keep ONE compile signature — the
-per-slot attention bodies gather their virtual contiguous cache row through
-the table, one layer at a time (``models.model.PagedRows``), and the
-written blocks are scattered back by page id afterwards. Page index 0 is a reserved SCRATCH
-page: unmapped table entries point at it, so gathers of never-written
-blocks read garbage that the engine's positional masks keep inert, and
+decode/verify jits, so the paged paths keep ONE compile signature. The
+decode step's attention reads a slot's K/V pages in place through its
+table row and the new rows are written into their pages afterwards; other
+paged leaves and the verify window gather a virtual contiguous row through
+the table, one layer at a time, and scatter the written blocks back by page
+id (``models.paged.PagedRows``). Page index 0 is a reserved SCRATCH
+page: unmapped table entries point at it, so reads of never-written
+blocks see garbage that the engine's positional masks keep inert, and
 writes from inactive slots or invalid verify-window blocks are redirected
 into it. Unpaged per-slot state (SSM conv/state — O(1) in sequence — and
 audio cross K/V) keeps the contiguous batch-row layout.

@@ -31,8 +31,9 @@ def test_kernel_phase_agrees_with_oracles():
     errs = smoke.kernel_phase(lstm_shapes=((16, 6, 6, 20), (8, 4, 128, 128)),
                               matmul_shape=(128, 256, 384),
                               attention_shape=(1, 4, 2, 256, 128),
+                              paged_shape=(4, 8, 2, 128, 16, 128),
                               interpret=True)
-    assert len(errs) == 10
+    assert len(errs) == 11
 
 
 def test_serve_phase_serves_every_request():

@@ -56,11 +56,13 @@ def test_chunked_paged_run_spans(tmp_path):
                          new_tokens=(1, 6))
     # one late arrival: the pool drains and the scheduler waits for it
     reqs.append(Request(len(reqs), 60.0, np.arange(1, 6, dtype=np.int32), 2))
-    steps = []
+    steps, pages_read = [], []
     decode0 = eng.masked_decode_step
 
     def counted(pool):
         steps.append(len(pool.decoding_slots()))
+        pos = pool.positions()[pool.decode_mask()]
+        pages_read.append(int((pos // pool.page + 1).sum()))
         return decode0(pool)
 
     eng.masked_decode_step = counted
@@ -83,6 +85,9 @@ def test_chunked_paged_run_spans(tmp_path):
         assert all(a[2] <= b[1] for a, b in zip(dec, dec[1:]))
         n_decode += bool(dec)
     assert n_decode == len(steps)
+    # each decode dispatch carries the pages its attention reads in place
+    assert [s[3].get("kv_pages_read") for s in spans
+            if s[0] == "engine.decode.dispatch"] == pages_read
     # every engine span lies in some tick
     assert all(any(_inside(s, t) for t in ticks)
                for s in spans if s[0].startswith("engine."))

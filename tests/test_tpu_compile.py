@@ -2,9 +2,10 @@
 
 Interpret mode enforces none of Mosaic's tiling or VMEM limits, so these
 tests lower every Pallas kernel with ``interpret=False``, and the engine's
-paged decode step at granite-3-8b widths, against a described ``v5e:2x2``
-topology, at the shapes ``chip_smoke.py`` runs on the chip. A compile that
-passes here is not a chip run: nothing executes.
+paged decode step at granite-3-8b and granite-4.0-h-small widths (its
+attention kernel through Mosaic too), against a described ``v5e:2x2``
+topology, at the shapes ``chip_smoke.py`` and the benchmark run on the chip.
+A compile that passes here is not a chip run: nothing executes.
 
 The topology is described inside a module fixture (never at import), so
 only the test worker that runs this file loads the TPU compiler.
@@ -26,6 +27,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 from repro.configs import get_config
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.int8_matmul import int8_matmul
+from repro.kernels.paged_attention import paged_decode_attention
 from repro.kernels.lstm_quant import quantize_lstm_weights
 from repro.kernels.lstm_seq import (lstm_seq_fused, lstm_seq_fused_quantized,
                                     lstm_stack_fused)
@@ -143,9 +145,40 @@ def test_flash_attention_compiles(one_chip):
     _fits(compiled)
 
 
-def _paged_decode_step(one_chip, layers):
-    cfg = dataclasses.replace(get_config("granite-3-8b"), num_layers=layers)
-    sc = ServeConfig(max_batch=16, max_len=4096, paged=True)
+def test_paged_attention_compiles(one_chip):
+    """The paged decode attention kernel alone, vmapped over the smoke's 16
+    slots as the decode step calls it, over one layer of a two-layer pool."""
+    slots, h, kvh, d, page, max_len = smoke.GRANITE_PAGED
+    mb = max_len // page
+    pool = jax.ShapeDtypeStruct((2, slots * mb + 1, page, kvh, d), jnp.bfloat16)
+    args = (jax.ShapeDtypeStruct((slots, h, d), jnp.bfloat16),
+            jax.ShapeDtypeStruct((slots, kvh, d), jnp.bfloat16),
+            jax.ShapeDtypeStruct((slots, kvh, d), jnp.bfloat16),
+            pool, pool, jax.ShapeDtypeStruct((slots, mb), jnp.int32),
+            jax.ShapeDtypeStruct((slots,), jnp.int32), jax.ShapeDtypeStruct((), jnp.int32))
+    step = jax.vmap(lambda q, kn, vn, kp, vp, t, p, layer: paged_decode_attention(
+        q, kn, vn, kp, vp, t, p, layer, interpret=False),
+        in_axes=(0, 0, 0, None, None, 0, 0, None))
+    compiled = _compile(step, args, one_chip)
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    _fits(compiled)
+
+
+def _granite4h_10l():
+    """granite-4.0-h-small cut as the benchmark serves it: one 10-layer
+    period (attention at layer 5), experts 0-8 of 72 held."""
+    base = get_config("granite-4.0-h-small")
+    return dataclasses.replace(base, num_layers=10, moe=dataclasses.replace(
+        base.moe, experts_held=9, first_expert=0))
+
+
+def _paged_decode_step(one_chip, monkeypatch, cfg, *, max_batch=16, max_len=4096):
+    """The engine's paged decode step compiled for a v5e at ``cfg``'s widths,
+    with the paged attention kernel lowered through Mosaic (on this CPU the
+    engine would otherwise take the kernel's jnp reference). Returns
+    (virtual row length, pool page count, pool, compiled)."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    sc = ServeConfig(max_batch=max_batch, max_len=max_len, paged=True)
     key = jax.random.PRNGKey(0)
     params = jax.eval_shape(lambda: init_model(cfg, key))
     engine = InferenceEngine(cfg, params=params, sc=sc)
@@ -153,13 +186,27 @@ def _paged_decode_step(one_chip, layers):
         cfg, max_batch=sc.max_batch, max_len=sc.max_len,
         page_size=sc.page_size).cache)
     mb = -(-(sc.max_len + sc.page_size) // sc.page_size) + 1
+    n_pages = sc.max_batch * mb + 1
     assert pool["k"].shape == page_defs(
-        cfg, num_pages=sc.max_batch * mb + 1, page_size=sc.page_size)["k"].shape
+        cfg, num_pages=n_pages, page_size=sc.page_size)["k"].shape
     vec = lambda dt: jax.ShapeDtypeStruct((sc.max_batch,), dt)  # noqa: E731
     args = (params, pool, vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
             jax.ShapeDtypeStruct((sc.max_batch, mb), jnp.int32))
     specs = jax.tree.map(lambda x: _spec_of(x, one_chip), args)
-    return cfg, mb * sc.page_size, engine._paged_decode.lower(*specs).compile()
+    return mb * sc.page_size, n_pages, pool, engine._paged_decode.lower(*specs).compile()
+
+
+DECODE_CELLS = {
+    "granite": lambda: (dataclasses.replace(get_config("granite-3-8b"),
+                                            num_layers=smoke.SERVE_LAYERS), 16, 4096),
+    "granite4h": lambda: (_granite4h_10l(), 48, 9216),
+}
+
+
+def _decode_cell(one_chip, monkeypatch, cell):
+    cfg, max_batch, max_len = DECODE_CELLS[cell]()
+    return (cfg, *_paged_decode_step(one_chip, monkeypatch, cfg,
+                                     max_batch=max_batch, max_len=max_len))
 
 
 def _chunk_step(one_chip):
@@ -182,12 +229,23 @@ def _chunk_step(one_chip):
 
 
 @pytest.mark.parametrize("layers", [smoke.NUMERICS_LAYERS, smoke.SERVE_LAYERS])
-def test_paged_decode_compiles_at_granite_widths(one_chip, layers):
+def test_paged_decode_compiles_at_granite_widths(one_chip, monkeypatch, layers):
     """The serving decode step at published widths with the smoke's pool:
-    16 slots, 4096 positions, parity-sized page pool. At 16 layers it fits
-    only because the model gathers each slot's pages one layer at a time
-    (``models.model.PagedRows``); a whole-stack gather needs 8 GiB more."""
-    _fits(_paged_decode_step(one_chip, layers)[2])
+    16 slots, 4096 positions, parity-sized page pool, the attention kernel
+    reading each slot's pages in place."""
+    cfg = dataclasses.replace(get_config("granite-3-8b"), num_layers=layers)
+    compiled = _paged_decode_step(one_chip, monkeypatch, cfg)[3]
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+def test_paged_decode_compiles_at_granite4h_widths(one_chip, monkeypatch):
+    """granite-4.0-h-small's decode step as ``granite4h-longdoc`` serves it:
+    48 slots of 9216 positions, Mamba-2 state rows beside one attention
+    layer's pages, within what one v5e program may use."""
+    compiled = _decode_cell(one_chip, monkeypatch, "granite4h")[4]
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _fits(compiled) <= USABLE_HBM_BYTES
 
 
 def _arrays(hlo: str):
@@ -196,18 +254,40 @@ def _arrays(hlo: str):
             for dt, dims in re.findall(r"\b(bf16|f16|f32|s8)\[([0-9,]*)\]", hlo)}
 
 
-@pytest.mark.parametrize("step", ["decode", "chunk"])
-def test_attention_reads_the_cache_in_place(one_chip, step):
-    """Serving attention contracts each KV head with its query group in
-    place: no array of the compiled step carries the slot's whole virtual
-    row together with the head dim and all query heads (a K/V repeated to
-    32 heads, as ``[..., rows, 32, 128]`` or ``[..., rows, 8, 4, 128]``),
-    and no K/V row is copied to f32. Scores carry rows and heads but no
-    head dim, so they pass."""
-    if step == "decode":
-        cfg, rows, compiled = _paged_decode_step(one_chip, smoke.SERVE_LAYERS)
-    else:
-        cfg, rows, compiled, _ = _chunk_step(one_chip)
+def _ops(hlo: str, kinds: str):
+    """(op, dims) of every ``kinds`` instruction's result in compiled HLO text."""
+    return [(op, tuple(int(n) for n in dims.split(",") if n)) for dims, op in re.findall(
+        rf"= \w+\[([0-9,]*)\]\S* ({kinds})\(", hlo)]
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk", "decode-granite4h"])
+def test_attention_reads_the_cache_in_place(one_chip, monkeypatch, step):
+    """Decode (both cells' widths): attention reads each slot's pages where
+    they lie, so no array of the compiled step carries a slot's whole
+    virtual row (4128 or 9248 rows) with the head dim, no dynamic-slice,
+    pad or copy has a layer's whole page array as its shape, and the pool
+    is updated in place (its bytes alias the step's output).
+
+    Chunk: attention contracts each KV head with its query group in place:
+    no array carries the row together with the head dim and all query heads
+    (a K/V repeated to 32 heads, as ``[..., rows, 32, 128]`` or ``[..., rows,
+    8, 4, 128]``), and no K/V row is copied to f32. Scores carry rows and
+    heads but no head dim, so they pass."""
+    if step.startswith("decode"):
+        cell = step.partition("-")[2] or "granite"
+        cfg, rows, n_pages, pool, compiled = _decode_cell(one_chip, monkeypatch, cell)
+        d = cfg.resolved_head_dim
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text, "no attention kernel in the decode step"
+        row_arrays = [(dt, dims) for dt, dims in _arrays(text) if rows in dims and d in dims]
+        assert not row_arrays, f"a whole virtual K/V row: {row_arrays}"
+        moved = [(op, dims) for op, dims in _ops(text, "dynamic-slice|pad|copy|copy-start")
+                 if n_pages in dims]
+        assert not moved, f"a layer's page array sliced, padded or copied: {moved}"
+        page_bytes = sum(pool[k].size * pool[k].dtype.itemsize for k in ("k", "v"))
+        assert compiled.memory_analysis().alias_size_in_bytes >= page_bytes
+        return
+    cfg, rows, compiled, _ = _chunk_step(one_chip)
     h, kvh = cfg.num_heads, cfg.num_kv_heads
     d = cfg.resolved_head_dim
     arrays = _arrays(compiled.as_text())
